@@ -27,12 +27,45 @@ Sections:
                    ``python -m repro.launch.dryrun --all --out ...``)
 
 ``python -m benchmarks.run [--fast] [--sections a,b,c]``
+
+Each section runs in a child process of its own, one after another, and
+this parent never imports jax: a process that has started a jax backend
+holds the host's chip, and a later child could not get it.
 """
 import argparse
 import os
+import subprocess
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# section -> the code its child runs ({fast} is the --fast flag)
+SECTIONS = {
+    "api": "from benchmarks import api_bench; api_bench.run(fast={fast})",
+    "dist": "from benchmarks import dist_bench; dist_bench.run(fast={fast})",
+    "balance": "from benchmarks import balance_bench; "
+               "balance_bench.run(fast={fast})",
+    "serve": "from benchmarks import serve_bench; "
+             "serve_bench.run(fast={fast})",
+    "quality": "from benchmarks import quality; quality.run(scale='small', "
+               "ks=(2, 8, 32), seeds=(0,) if {fast} else (0, 1))",
+    "large_k": "from benchmarks import large_k; "
+               "large_k.run(ks=(64, 256) if {fast} else (64, 256, 1024))",
+    "balancer": "from benchmarks import balancer_stats; "
+                "balancer_stats.run()",
+    "kernels": "from benchmarks import kernels_bench; "
+               "kernels_bench.run(fast={fast})",
+    "scaling": "from benchmarks import scaling; "
+               "scaling.run(pes=(1, 2, 4) if {fast} else (1, 2, 4, 8))",
+    "roofline": "import os; from benchmarks import roofline; "
+                "roofline.run('artifacts/dryrun') "
+                "if os.path.isdir('artifacts/dryrun') else "
+                "print('roofline,0,skipped (run repro.launch.dryrun --all "
+                "--out artifacts/dryrun first)')",
+}
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="smallest instances (CI mode)")
@@ -40,44 +73,23 @@ def main() -> None:
                     "large_k,balancer,kernels,scaling")
     args = ap.parse_args()
     sections = args.sections.split(",")
-    print("name,us_per_call,derived")
-
-    if "api" in sections:
-        from . import api_bench
-        api_bench.run(fast=args.fast)
-    if "dist" in sections:
-        from . import dist_bench
-        dist_bench.run(fast=args.fast)
-    if "balance" in sections:
-        from . import balance_bench
-        balance_bench.run(fast=args.fast)
-    if "serve" in sections:
-        from . import serve_bench
-        serve_bench.run(fast=args.fast)
-    if "quality" in sections:
-        from . import quality
-        quality.run(scale="small", ks=(2, 8, 32),
-                    seeds=(0,) if args.fast else (0, 1))
-    if "large_k" in sections:
-        from . import large_k
-        large_k.run(ks=(64, 256) if args.fast else (64, 256, 1024))
-    if "balancer" in sections:
-        from . import balancer_stats
-        balancer_stats.run()
-    if "kernels" in sections:
-        from . import kernels_bench
-        kernels_bench.run(fast=args.fast)
-    if "scaling" in sections:
-        from . import scaling
-        scaling.run(pes=(1, 2, 4) if args.fast else (1, 2, 4, 8))
-    if "roofline" in sections:
-        from . import roofline
-        if os.path.isdir("artifacts/dryrun"):
-            roofline.run("artifacts/dryrun")
-        else:
-            print("roofline,0,skipped (run repro.launch.dryrun --all "
-                  "--out artifacts/dryrun first)")
+    unknown = [s for s in sections if s not in SECTIONS]
+    if unknown:
+        ap.error(f"unknown sections {unknown}; expected {list(SECTIONS)}")
+    print("name,us_per_call,derived", flush=True)
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (os.path.join(ROOT, "src"), env.get("PYTHONPATH")) if p)
+    failed = []
+    for name in sections:
+        code = SECTIONS[name].format(fast=args.fast)
+        if subprocess.run([sys.executable, "-c", code], cwd=ROOT,
+                          env=env).returncode:
+            failed.append(name)
+    if failed:
+        print(f"failed sections: {','.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

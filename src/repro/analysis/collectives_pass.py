@@ -10,7 +10,7 @@ Three rules, all aimed at the SPMD failure mode that matters at scale
   ``shard_map`` body issue different collective sequences: whichever
   branch a PE takes, its peers must issue the *same* collectives in
   the same order or the program deadlocks.
-* ``SPMD003`` — a ``shard_map`` site staged with ``check_rep=False``
+* ``SPMD003`` — a ``shard_map`` site staged with ``check=False``
   (jax's own replication checker disabled) that is not recorded in the
   reviewed ``analysis/allowlist.toml`` with a reason.
 """
@@ -19,6 +19,7 @@ from __future__ import annotations
 
 from typing import Any, Iterator, List, Tuple
 
+from ..dist.compat import CHECK_KW
 from .findings import Finding, Report, rel_to_repo
 
 # primitives that communicate across a named mesh axis
@@ -58,7 +59,7 @@ def _source_site(eqn: Any) -> Tuple[str, int, str]:
     try:
         from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
     except Exception:
         frame = None
     if frame is None:
@@ -66,7 +67,9 @@ def _source_site(eqn: Any) -> Tuple[str, int, str]:
     return (
         rel_to_repo(frame.file_name),
         int(frame.start_line),
-        frame.function_name,
+        # frames carry the qualified name (``outer.<locals>.inner``);
+        # allowlist entries name the innermost function
+        frame.function_name.rsplit(".", 1)[-1],
     )
 
 
@@ -191,13 +194,13 @@ def run(
                 # the shard_map eqn was bound under the tracing proxy;
                 # anchor it on the patched builder the entry came from
                 file, line, func = hint[0], 0, hint[1]
-            if sm.params.get("check_rep", True) is False:
+            if sm.params.get(CHECK_KW, True) is False:
                 report.add(
                     Finding(
                         rule="SPMD003",
                         pass_name="collectives",
                         message=(
-                            "shard_map staged with check_rep=False "
+                            "shard_map staged with check=False "
                             "(replication checking disabled) — must "
                             "be allowlisted with a reason"
                         ),

@@ -32,7 +32,7 @@ ALLOWLIST_PATH = os.path.join(os.path.dirname(__file__), "allowlist.toml")
 
 # allowlist table names -> the finding rules they may suppress
 ALLOWLIST_KINDS = {
-    "check_rep": ("SPMD003",),
+    "unchecked": ("SPMD003",),
     "overflow": ("OFL001",),
     "lint": ("LNT001", "LNT002", "LNT003"),
 }

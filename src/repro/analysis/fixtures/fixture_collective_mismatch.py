@@ -2,7 +2,7 @@
 whose branches issue different collective sequences (one psums, the
 other computes locally). If PEs diverge on the predicate, the psum
 deadlocks — the collectives pass must flag this (SPMD002), and the
-``check_rep=False`` staging is deliberately *not* allowlisted
+``check=False`` staging is deliberately *not* allowlisted
 (SPMD003).
 """
 
@@ -40,7 +40,7 @@ def captured(P: int = 2) -> List[Tuple[str, Any]]:
             mesh=mesh,
             in_specs=PS("pe"),
             out_specs=PS("pe"),
-            check_rep=False,
+            check=False,
         )
     )
     x = jnp.zeros((P, 4), jnp.int32)

@@ -1,6 +1,6 @@
 """Seeded lint violations (AST-scanned only, never imported by the
 pipeline): a jit-staged function calling host numpy and the Python
-RNG (LNT001), a ``shard_map`` call without ``check_rep=`` (LNT002),
+RNG (LNT001), a ``shard_map`` call without ``check=`` (LNT002),
 and a ``.item()`` device sync treated as serve-hot-path code
 (LNT003).
 """
@@ -20,7 +20,7 @@ def staged_bad(x):
 
 
 def build(mesh, spec, shard_map):
-    return shard_map(  # LNT002: no explicit check_rep=
+    return shard_map(  # LNT002: no explicit check=
         lambda v: v,
         mesh=mesh,
         in_specs=spec,

@@ -6,9 +6,10 @@
   staged function either leak a tracer or silently bake a host value
   into the compiled program. Dtype constructors (``np.int32(...)``,
   ``np.iinfo``...) are concrete compile-time constants and stay legal.
-* ``LNT002`` — a ``shard_map`` call without an explicit ``check_rep=``
-  keyword: the default flips semantics between jax versions, and the
-  collectives pass keys its allowlist on the explicit value.
+* ``LNT002`` — a ``shard_map`` call without an explicit ``check=``
+  keyword (``repro.dist.compat.shard_map``): the replication check is
+  a reviewed choice per site, and the collectives pass keys its
+  allowlist on it.
 * ``LNT003`` — ``.item()`` / ``jax.device_get`` in the serve-dispatch
   hot path (``src/repro/serve``): a device sync per request melts the
   batched dispatch throughput the serve tier exists to provide.
@@ -177,14 +178,14 @@ def check_file(
         chain = _attr_chain(call.func)
         if chain and chain[-1] == "shard_map":
             kw_names = {kw.arg for kw in call.keywords}
-            if "check_rep" not in kw_names:
+            if "check" not in kw_names:
                 report.add(
                     Finding(
                         rule="LNT002",
                         pass_name="lint",
                         message=(
                             "shard_map call without an explicit "
-                            "check_rep= keyword"
+                            "check= keyword"
                         ),
                         file=file,
                         line=call.lineno,

@@ -6,7 +6,9 @@ keeps resident — operands, outputs, scratch, and the transient
 row-tile workspaces — as ``(name, shape, dtype)`` entries derived from
 the kernel signatures in ``repro.kernels``. Summing the inventory
 gives a worst-case VMEM byte count as a pure function of
-``(row_tile, bucket, dtype)``; the pass cross-checks it against the
+``(row_tile, bucket, dtype)``, each tensor at its size in Mosaic's
+``T(8, 128)`` tiled layout (last two dims padded to 8 sublanes and 128
+lanes: an ``(R, 1)`` column costs 512 B per row); the pass cross-checks it against the
 runtime planning formulas (``lp_move_vmem_bytes`` & co) that gate the
 fused->composed fallback (reported via ``dispatch.report_fallback``),
 so the fallback boundary is unit-testable without a TPU.
@@ -29,6 +31,7 @@ from typing import Callable, Dict, List, Tuple
 from .findings import Finding, Report
 
 ITEM = 4  # every kernel tensor is an int32/float32 laneset
+SUBLANES, LANES = 8, 128  # the 32-bit VMEM tile
 
 Tensor = Tuple[str, Tuple[int, ...]]
 
@@ -94,7 +97,7 @@ def bal_round_inventory(
 
 def seg_merge_inventory(L: int) -> List[Tensor]:
     """Resident lanesets of ``kernels.seg_merge.seg_merge``."""
-    Lp = max(2, _next_pow2(L))
+    Lp = max(LANES, _next_pow2(L))
     names = [
         "src",  # input keys
         "dst",
@@ -110,11 +113,16 @@ def seg_merge_inventory(L: int) -> List[Tensor]:
     return [(name, (1, Lp)) for name in names]
 
 
+def _round_up(x: int, mult: int) -> int:
+    return (x + mult - 1) // mult * mult
+
+
 def inventory_bytes(tensors: List[Tensor]) -> int:
     total = 0
     for _, shape in tensors:
-        size = ITEM
-        for dim in shape:
+        *lead, rows, cols = shape
+        size = ITEM * _round_up(rows, SUBLANES) * _round_up(cols, LANES)
+        for dim in lead:
             size *= dim
         total += size
     return total
@@ -125,8 +133,8 @@ def _grids() -> Dict[str, List[dict]]:
     lp: List[dict] = []
     bal: List[dict] = []
     for row_tile in (8, 16):
-        for R in (128, 512, 2048, 8192, 32768):
-            for D in (8, 16, 32):
+        for R in (128, 512, 1024, 2048, 8192):
+            for D in (128, 256, 384):
                 for flag in (False, True):
                     lp.append(
                         dict(R=R, D=D, row_tile=row_tile, fit_sum=flag)

@@ -11,20 +11,48 @@ import sys
 from typing import Any, List, Optional, Sequence
 
 _FLAG = "--xla_force_host_platform_device_count"
+_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+_CACHE_MIN_SECS_ENV = "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"
+# <checkout>/.jax_cache: a fixed path, since a cache whose directory
+# moves is never found again (this file is <checkout>/src/repro/api/...)
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
 
 def jax_backend_initialized() -> bool:
     """True iff a jax backend has been created in this process (at which
     point the device count is locked and XLA_FLAGS edits are ignored)."""
     xb = sys.modules.get("jax._src.xla_bridge")
-    if xb is None:
-        return False
-    if hasattr(xb, "_backends"):        # jax 0.4.x: dict filled at init
-        return bool(xb._backends)
-    # private layout changed (newer jax): report initialized so that
-    # force_host_devices fails loudly instead of silently editing flags
-    # that may never be read
-    return True
+    return xb is not None and bool(xb._backends)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    the directory is left alone. Otherwise the cache goes to
+    ``<checkout>/.jax_cache``. Either way every compile is cached, not
+    only those over JAX's default one second: the per-level programs of
+    the multilevel scheme are small and many. Entry points call this
+    before JAX initializes; child processes inherit the setting.
+    """
+    os.environ.setdefault(_CACHE_MIN_SECS_ENV, "0")
+    os.environ.setdefault(_CACHE_ENV, DEFAULT_CACHE_DIR)
+    if "jax" in sys.modules:     # imported already: its config is read
+        import jax
+        jax.config.update("jax_compilation_cache_dir",
+                          os.environ[_CACHE_ENV])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          float(os.environ[_CACHE_MIN_SECS_ENV]))
+    return os.environ[_CACHE_ENV]
+
+
+def local_tpu_chips() -> int:
+    """TPU chips on this host's PCI bus. Opens no device and starts no
+    backend, so a parent process can ask without holding a chip."""
+    from jax._src import hardware_utils
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
 
 
 def device_count() -> int:
@@ -68,11 +96,13 @@ def device_slices(num_slices: int,
                     f"{feas_per} device(s)")
         else:
             hint = "no carve of this shape is feasible"
+        if devs[0].platform == "cpu":
+            hint += (". Force more host devices with force_host_devices() "
+                     "before any jax computation")
         raise RuntimeError(
             f"cannot carve {num_slices} slice(s) of {devices_per_slice} "
             f"device(s) ({need} total): only {len(devs)} device(s) "
-            f"available; {hint}. Force more host devices with "
-            "force_host_devices() before any jax computation")
+            f"available ({devs[0].platform}); {hint}")
     return [devs[i * devices_per_slice:(i + 1) * devices_per_slice]
             for i in range(num_slices)]
 

@@ -127,7 +127,7 @@ def greedy_select(vals, tgt_blk, src_blk, cand_w, block_w, l_max):
         return block_w, accept
 
     block_w, accept = jax.lax.fori_loop(
-        0, m, body, (block_w, jnp.zeros((m,), jnp.bool_)))
+        0, m, body, (block_w, jnp.zeros_like(vals, dtype=jnp.bool_)))
     return accept, block_w
 
 

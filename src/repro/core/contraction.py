@@ -36,7 +36,8 @@ def dedup_arcs(csrc: np.ndarray, cdst: np.ndarray, w: np.ndarray,
         if csrc.size:
             from ..kernels.seg_merge.seg_merge import seg_merge_vmem_bytes
             dispatch.report_fallback(
-                "seg_merge", seg_merge_vmem_bytes(csrc.size),
+                "seg_merge", seg_merge_vmem_bytes(
+                    seg_ops.dedup_records(csrc, cdst)),
                 detail="dedup_arcs (int32/VMEM envelope)")
     keep = csrc != cdst
     csrc, cdst, w = csrc[keep], cdst[keep], w[keep]

@@ -1,26 +1,25 @@
-"""Version-compat shims for jax APIs the distributed subsystem relies on.
+"""The one module that knows the installed JAX release (0.9).
 
-The repo targets the baked-in toolchain (jax 0.4.x) but keeps working on
-newer releases where ``shard_map`` graduated out of ``jax.experimental``
-and ``make_mesh`` grew an ``axis_types`` parameter.
+Call sites pass ``check=`` to :func:`shard_map`; only this module names
+JAX's own keyword for the replication check (``CHECK_KW``), which is
+also the parameter the static analysis reads on a staged ``shard_map``
+equation.
 """
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # jax < 0.5
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+CHECK_KW = "check_vma"
+
+
+def shard_map(f, *, mesh, in_specs, out_specs, check: bool):
+    """``jax.shard_map`` with the replication check named ``check``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **{CHECK_KW: check})
 
 
 def make_mesh(axis_shapes, axis_names):
-    """jax.make_mesh that tolerates the absence of AxisType (jax 0.4.x)."""
-    if hasattr(jax.sharding, "AxisType"):
-        try:
-            return jax.make_mesh(
-                axis_shapes, axis_names,
-                axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-        except TypeError:
-            pass
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with every axis in ``Auto`` mode."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))

@@ -160,7 +160,7 @@ def _build_balance_round_fn(mesh, P, k, n, n_loc, n_ghost, top_m, use_grid,
     n_pe = 9 if fused else 10
     fn = shard_map(per_pe, mesh=mesh,
                    in_specs=(pe,) * n_pe + (rep, rep, rep),
-                   out_specs=(pe, pe, pe, pe), check_rep=not fused)
+                   out_specs=(pe, pe, pe, pe), check=not fused)
     return jax.jit(fn)
 
 
@@ -342,7 +342,7 @@ def _build_enforce_fn(mesh, P, n, n_loc, use_grid):
 
     pe = PS("pe")
     fn = shard_map(per_pe, mesh=mesh, in_specs=(pe, pe, pe, PS()),
-                   out_specs=(pe, pe), check_rep=True)
+                   out_specs=(pe, pe), check=True)
     return jax.jit(fn)
 
 

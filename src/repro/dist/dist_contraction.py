@@ -108,7 +108,7 @@ def _build_exchange_fn(mesh, P: int, S_e: int, use_grid: bool,
 
     pe = PS("pe")
     fn = shard_map(per_pe, mesh=mesh, in_specs=(pe, pe),
-                   out_specs=(pe, pe, pe, pe), check_rep=not fused)
+                   out_specs=(pe, pe, pe, pe), check=not fused)
     return jax.jit(fn)
 
 

@@ -381,10 +381,10 @@ def _build_cluster_fn(mesh, P, n, n_loc, n_ghost, B, num_iterations,
 
     pe = PS("pe")
     rep = PS()
-    # check_rep: pallas_call has no replication rule under shard_map
+    # check: pallas_call has no replication rule under shard_map
     fn = shard_map(per_pe, mesh=mesh,
                    in_specs=(pe, pe, pe, pe, pe, pe, pe, pe, rep, rep),
-                   out_specs=pe, check_rep=not fused)
+                   out_specs=pe, check=not fused)
     return jax.jit(fn)
 
 
@@ -517,7 +517,7 @@ def _build_refine_fn(mesh, P, k, n_loc, n_ghost, B, num_iterations,
     rep = PS()
     fn = shard_map(per_pe, mesh=mesh,
                    in_specs=(pe, pe, pe, pe, pe, pe, pe, pe, rep, rep),
-                   out_specs=pe, check_rep=True)
+                   out_specs=pe, check=True)
     return jax.jit(fn)
 
 
@@ -644,7 +644,7 @@ def _build_urefine_fn(mesh, P, k, n_loc, n_ghost, B, num_iterations,
     rep = PS()
     fn = shard_map(per_pe, mesh=mesh,
                    in_specs=(pe, pe, pe, pe, pe, pe, pe, pe, rep, rep),
-                   out_specs=pe, check_rep=True)
+                   out_specs=pe, check=True)
     return jax.jit(fn)
 
 

@@ -17,7 +17,10 @@ Two layers, split so the policy is a pure unit-testable object:
   killed). Scale-up spawns one worker from the command template;
   scale-down SIGTERMs the youngest spawned worker, which drains
   gracefully (finishes in-flight, resolves queued tickets as
-  ``server_closed``, deregisters) before exiting.
+  ``server_closed``, deregisters) before exiting. A worker that runs on
+  the host's TPU opens every chip of the host, so where the workers
+  would hold chips the scaler keeps at most one alive. Worker output
+  goes to a log file per worker (``log_dir``), never to /dev/null.
 """
 
 from __future__ import annotations
@@ -27,6 +30,7 @@ import os
 import signal
 import subprocess
 import sys
+import tempfile
 import threading
 from typing import Dict, List, Optional, Sequence
 
@@ -109,12 +113,26 @@ class AutoscalePolicy:
         return 0
 
 
+def _workers_hold_chips(env: Dict[str, str]) -> bool:
+    """Whether workers started with ``env`` would open the host's TPU."""
+    platforms = env.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return False
+    from ..api.runtime import local_tpu_chips
+
+    return local_tpu_chips() > 0
+
+
 class ProcessScaler:
     """Spawn/stop fabric worker processes for the front door.
 
     ``worker_args`` is everything after ``repro.launch.fabric worker``
     except ``--server-id`` (generated per spawn) — typically at least
-    ``--frontdoor host:port``.
+    ``--frontdoor host:port``. Where the host has a TPU and the workers'
+    ``JAX_PLATFORMS`` allows it, at most one worker is alive at a time
+    (``worker_cap``). Each worker's stdout and stderr go to
+    ``<log_dir>/<server id>.log`` (``log_dir`` defaults to a fresh
+    temporary directory, see :attr:`log_dir`).
     """
 
     def __init__(
@@ -122,10 +140,13 @@ class ProcessScaler:
         worker_args: Sequence[str],
         env: Optional[Dict[str, str]] = None,
         id_prefix: str = "auto",
+        log_dir: Optional[str] = None,
     ):
         self._worker_args = list(worker_args)
         self._env = dict(env) if env is not None else dict(os.environ)
         self._id_prefix = id_prefix
+        self.worker_cap = 1 if _workers_hold_chips(self._env) else None
+        self.log_dir = log_dir or tempfile.mkdtemp(prefix="repro-fabric-")
         self._lock = threading.Lock()
         self._procs: List[subprocess.Popen] = []
         self._spawned = 0
@@ -140,9 +161,14 @@ class ProcessScaler:
             self._reap_locked()
             return len(self._procs)
 
-    def scale_up(self) -> str:
-        """Spawn one worker; returns its server id."""
+    def scale_up(self) -> Optional[str]:
+        """Spawn one worker; returns its server id, or None when the
+        live workers already hold every chip they may."""
         with self._lock:
+            self._reap_locked()
+            if self.worker_cap is not None and \
+                    len(self._procs) >= self.worker_cap:
+                return None
             self._spawned += 1
             sid = f"{self._id_prefix}-{os.getpid()}-{self._spawned}"
             cmd = [
@@ -154,12 +180,10 @@ class ProcessScaler:
                 sid,
             ]
             cmd += self._worker_args
-            proc = subprocess.Popen(
-                cmd,
-                env=self._env,
-                stdout=subprocess.DEVNULL,
-                stderr=subprocess.DEVNULL,
-            )
+            with open(os.path.join(self.log_dir, f"{sid}.log"), "ab") as log:
+                proc = subprocess.Popen(
+                    cmd, env=self._env, stdout=log, stderr=subprocess.STDOUT
+                )
             self._procs.append(proc)
             return sid
 

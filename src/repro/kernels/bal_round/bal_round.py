@@ -50,6 +50,7 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 
+from ..dispatch import tiled_bytes
 from ..lp_move.lp_move import _h32
 
 I32_MAX = np.int32(np.iinfo(np.int32).max)
@@ -68,19 +69,19 @@ def _scores_kernel(*refs, R, D, TA, restricted):
     salt = salt_ref[0, 0]
 
     def tile(t, _):
-        rows = (pl.dslice(t * TA, TA), slice(None))
-        nlab = pl.load(nlab_ref, rows)               # (TA, D)
-        nw = pl.load(nw_ref, rows)
-        nbw = pl.load(nbw_ref, rows)
-        nlm = pl.load(nlm_ref, rows)
-        own = pl.load(own_ref, rows)                 # (TA, 1)
-        vw = pl.load(vw_ref, rows)
+        rows = (pl.ds(t * TA, TA), slice(None))
+        nlab = nlab_ref[rows]  # (TA, D)
+        nw = nw_ref[rows]
+        nbw = nbw_ref[rows]
+        nlm = nlm_ref[rows]
+        own = own_ref[rows]  # (TA, 1)
+        vw = vw_ref[rows]
         validn = nlab >= 0
         # target must fit (w <= budget - c, exact at the int32 boundary)
         # and differ from the own block
         ok = (nbw <= (nlm - vw)) & (nlab != own) & validn
         if restricted:
-            ok &= pl.load(npar_ref, rows) == pl.load(opar_ref, rows)
+            ok &= npar_ref[rows] == opar_ref[rows]
         # conn[r, j] = sum_i w[r, i] * [lab[r, i] == lab[r, j]]
         eq = nlab[:, :, None] == nlab[:, None, :]    # (TA, D, D)
         conn = jnp.sum(jnp.where(eq, nw[:, :, None], 0), axis=1)
@@ -100,16 +101,16 @@ def _scores_kernel(*refs, R, D, TA, restricted):
                            axis=1, keepdims=True)
         has_adj = best >= 0
         g = jnp.where(has_adj, best - own_conn, -own_conn)
-        tgt = jnp.where(has_adj, tgt_adj, pl.load(fbt_ref, rows))
-        movable = (pl.load(ovr_ref, rows) != 0) & \
-            (has_adj | (pl.load(fbok_ref, rows) != 0)) & \
-            (pl.load(vld_ref, rows) != 0)
+        tgt = jnp.where(has_adj, tgt_adj, fbt_ref[rows])
+        movable = (ovr_ref[rows] != 0) & \
+            (has_adj | (fbok_ref[rows] != 0)) & \
+            (vld_ref[rows] != 0)
         gf = g.astype(jnp.float32)
         cv = jnp.maximum(vw.astype(jnp.float32), 1.0)
         rel = jnp.where(g >= 0, gf * cv, gf / cv)
         rel = jnp.where(movable, rel, NEG_INF)
-        pl.store(rel_ref, rows, rel)
-        pl.store(tgt_ref, rows, tgt)
+        rel_ref[rows] = rel
+        tgt_ref[rows] = tgt
         return 0
 
     lax.fori_loop(0, R // TA, tile, 0)
@@ -169,12 +170,13 @@ def _pick_kernel(vals_ref, tgt_ref, blk_ref, cw_ref, bw_ref, lm_ref,
         cwd = jnp.where(ok, c, 0)
         bw = bw - jnp.where(iota_k == b, cwd, 0) \
                 + jnp.where(iota_k == t, cwd, 0)
-        acc = acc | (sel & ok)
+        # int32, not bool: Mosaic cannot carry an i1 vector through a loop
+        acc = jnp.where(sel & ok, 1, acc)
         return bw, acc
 
     bw, acc = lax.fori_loop(
-        0, M, body, (bw_ref[...], jnp.zeros((1, M), jnp.bool_)))
-    acc_ref[...] = acc.astype(jnp.int32)
+        0, M, body, (bw_ref[...], jnp.zeros((1, M), jnp.int32)))
+    acc_ref[...] = acc
     bwout_ref[...] = bw
 
 
@@ -199,8 +201,9 @@ def greedy_pick(vals, tgt_blk, src_blk, cand_w, block_w, l_max, *,
 
 def bal_scores_vmem_bytes(R: int, D: int, row_tile: int = 8,
                           restricted: bool = False) -> int:
-    """Planning estimate of the scores kernel's VMEM working set."""
-    slabs = (5 if restricted else 4) * R * D * 4
-    cols = (9 if restricted else 8) * R * 4
-    cube = row_tile * D * D * 4
+    """Planning estimate of the scores kernel's VMEM working set, each
+    array at its tiled size (``dispatch.tiled_bytes``)."""
+    slabs = (5 if restricted else 4) * tiled_bytes(R, D)
+    cols = (9 if restricted else 8) * tiled_bytes(R, 1)
+    cube = tiled_bytes(row_tile, D, D)
     return slabs + cols + cube

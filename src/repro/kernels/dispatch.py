@@ -14,7 +14,7 @@ of the three fused hot loops (docs/KERNELS.md):
     anywhere else.
 
 Fused wrappers also fall back to the composed path per call site when a
-shape exceeds the kernel's VMEM budget (see ``fits_vmem``); the fallback
+shape exceeds the kernel's VMEM budget (see ``tiled_bytes``); the fallback
 is safe because both paths are bit-identical by construction, and it is
 *observable*, not silent: every decision is recorded via
 ``report_fallback`` (a one-shot warning per kernel plus a
@@ -60,10 +60,19 @@ def kernel_interpret() -> bool:
     return not _default_backend_is_tpu()
 
 
-def fits_vmem(*arrays_bytes: int, budget: int = VMEM_BUDGET_BYTES) -> bool:
-    """Whole-chunk kernels keep every operand resident in VMEM; callers
-    sum their operand footprints and fall back to composed beyond this."""
-    return sum(arrays_bytes) <= budget
+def tiled_bytes(*shape: int) -> int:
+    """VMEM bytes of a 32-bit array in Mosaic's ``T(8, 128)`` layout.
+
+    The last two dims pad to 8 sublanes and 128 lanes, so an ``(R, 1)``
+    column costs 512 B per row and a ``(1, L)`` laneset 32 B per lane.
+    The planning formulas count every resident array this way: counted
+    at 4 B per element, they admitted shapes the compiler refuses.
+    """
+    *lead, rows, cols = (1, *shape) if len(shape) == 1 else shape
+    n = 4 * (-(-rows // 8) * 8) * (-(-cols // 128) * 128)
+    for d in lead:
+        n *= d
+    return n
 
 
 # --- fallback observability -------------------------------------------
@@ -91,9 +100,10 @@ def report_fallback(kernel: str, estimated_bytes: int,
     if kernel not in _fallback_warned:
         _fallback_warned.add(kernel)
         warnings.warn(
-            f"fused kernel {kernel!r} fell back to the composed path: "
-            f"estimated working set {int(estimated_bytes)} B exceeds "
-            f"the {int(budget)} B VMEM budget ({detail or 'no detail'})"
+            f"fused kernel {kernel!r} fell back to the composed path "
+            f"({detail or 'no detail'}): the shape is outside the "
+            f"kernel's gate (estimated working set "
+            f"{int(estimated_bytes)} B, VMEM budget {int(budget)} B)"
             "; results are identical but the kernel speedup is lost "
             "(warning once per kernel)",
             UserWarning, stacklevel=3)

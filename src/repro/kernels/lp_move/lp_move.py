@@ -71,6 +71,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..dispatch import tiled_bytes
+
 I32_MAX = np.int32(np.iinfo(np.int32).max)
 
 
@@ -98,18 +100,18 @@ def _kernel(*refs, R, D, TA, TB, fit_sum):
     # ---- phase A: gain + argmax + admission per row tile ---------------
     def phase_a(t, _):
         r0 = t * TA
-        rows = (pl.dslice(r0, TA), slice(None))
-        nlab = pl.load(nlab_ref, rows)               # (TA, D)
-        nw = pl.load(nw_ref, rows)
-        ncw = pl.load(ncw_ref, rows)
-        own = pl.load(own_ref, rows)                 # (TA, 1)
-        vw = pl.load(vw_ref, rows)
+        rows = (pl.ds(r0, TA), slice(None))
+        nlab = nlab_ref[rows]  # (TA, D)
+        nw = nw_ref[rows]
+        ncw = ncw_ref[rows]
+        own = own_ref[rows]  # (TA, 1)
+        vw = vw_ref[rows]
         validn = nlab >= 0
         staying = nlab == own
         if fit_sum:
             fits = ((ncw + vw) <= W) | staying
         else:
-            nbud = pl.load(nbud_ref, rows)
+            nbud = nbud_ref[rows]
             fits = (ncw <= (nbud - vw)) | staying
         fits = fits & validn
         # conn[r, j] = sum_i w[r, i] * [lab[r, i] == lab[r, j]]
@@ -130,9 +132,9 @@ def _kernel(*refs, R, D, TA, TB, fit_sum):
         own_conn = jnp.sum(jnp.where(staying & validn, nw, 0), axis=1,
                            keepdims=True)
         mv = (best > own_conn) & (tgt != own) & (tgt < I32_MAX) & (best > 0)
-        pl.store(tgt_ref, rows, jnp.where(mv, tgt, own))
-        pl.store(pmove_ref, rows, mv.astype(jnp.int32))
-        pl.store(light_ref, rows, light)
+        tgt_ref[rows] = jnp.where(mv, tgt, own)
+        pmove_ref[rows] = mv.astype(jnp.int32)
+        light_ref[rows] = light
         return 0
 
     lax.fori_loop(0, R // TA, phase_a, 0)
@@ -145,18 +147,18 @@ def _kernel(*refs, R, D, TA, TB, fit_sum):
 
     def phase_b1(t, _):
         r0 = t * TB
-        rows = (pl.dslice(r0, TB), slice(None))
-        tgt_v = pl.load(tgt_ref, rows)               # (TB, 1)
-        light_v = pl.load(light_ref, rows)
-        pmove_v = pl.load(pmove_ref, rows)
+        rows = (pl.ds(r0, TB), slice(None))
+        tgt_v = tgt_ref[rows]  # (TB, 1)
+        light_v = light_ref[rows]
+        pmove_v = pmove_ref[rows]
         d_in = jnp.sum(jnp.where(tgt_u == tgt_v, mvw_u, 0), axis=1,
                        keepdims=True)
         d_out = jnp.sum(jnp.where(own_u == tgt_v, mvw_u, 0), axis=1,
                         keepdims=True)
         new_cw = light_v + d_in - d_out
         cand = (pmove_v != 0) & (new_cw > W)
-        pl.store(newcw_ref, rows, new_cw)
-        pl.store(cand_ref, rows, cand.astype(jnp.int32))
+        newcw_ref[rows] = new_cw
+        cand_ref[rows] = cand.astype(jnp.int32)
         return 0
 
     lax.fori_loop(0, R // TB, phase_b1, 0)
@@ -169,11 +171,11 @@ def _kernel(*refs, R, D, TA, TB, fit_sum):
 
     def phase_b2(t, _):
         r0 = t * TB
-        rows = (pl.dslice(r0, TB), slice(None))
-        tgt_v = pl.load(tgt_ref, rows)
-        cand_v = pl.load(cand_ref, rows) != 0
-        pmove_v = pl.load(pmove_ref, rows) != 0
-        new_cw = pl.load(newcw_ref, rows)
+        rows = (pl.ds(r0, TB), slice(None))
+        tgt_v = tgt_ref[rows]
+        cand_v = cand_ref[rows] != 0
+        pmove_v = pmove_ref[rows] != 0
+        new_cw = newcw_ref[rows]
         iota_v = r0 + lax.broadcasted_iota(jnp.int32, (TB, 1), 0)
         rk_v = _h32(v0 + iota_v, salt2)
         same = tgt_u == tgt_v                        # (TB, R)
@@ -185,8 +187,7 @@ def _kernel(*refs, R, D, TA, TB, fit_sum):
                          keepdims=True)
         allowed = jnp.maximum(W - (new_cw - moved_in), 0)
         revert = cand_v & (within > allowed)
-        pl.store(moved_ref, rows,
-                 (pmove_v & ~revert).astype(jnp.int32))
+        moved_ref[rows] = (pmove_v & ~revert).astype(jnp.int32)
         return 0
 
     lax.fori_loop(0, R // TB, phase_b2, 0)
@@ -228,9 +229,10 @@ def lp_move_chunk(nlab, nw, ncw, own, vw, scal, salt, nbud=None, *,
 def lp_move_vmem_bytes(R: int, D: int, row_tile: int = 8,
                        fit_sum: bool = True) -> int:
     """Planning estimate of the kernel's VMEM working set (operands +
-    scratch + the (TA, D, D) equality cube and (TB, R) pairwise masks)."""
-    slabs = (3 if fit_sum else 4) * R * D * 4
-    cols = 8 * R * 4                      # own/vw/outputs/scratch columns
-    cube = row_tile * D * D * 4
-    pairwise = 4 * row_tile * R * 4
+    scratch + the (TA, D, D) equality cube and (TB, R) pairwise masks),
+    each array at its tiled size (``dispatch.tiled_bytes``)."""
+    slabs = (3 if fit_sum else 4) * tiled_bytes(R, D)
+    cols = 8 * tiled_bytes(R, 1)          # own/vw/outputs/scratch columns
+    cube = tiled_bytes(row_tile, D, D)
+    pairwise = 4 * tiled_bytes(row_tile, R)
     return slabs + cols + cube + pairwise

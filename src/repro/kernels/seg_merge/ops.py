@@ -11,8 +11,13 @@ from __future__ import annotations
 
 import numpy as np
 
-from .seg_merge import I32_MAX, _next_pow2, seg_merge, seg_merge_vmem_bytes
+from .seg_merge import I32_MAX, padded_lanes, seg_merge, seg_merge_vmem_bytes
 from ..dispatch import VMEM_BUDGET_BYTES
+
+
+def dedup_records(csrc: np.ndarray, cdst: np.ndarray) -> int:
+    """Records the kernel sorts: self loops are dropped before it."""
+    return int(np.count_nonzero(csrc != cdst))
 
 
 def dedup_fits(csrc: np.ndarray, cdst: np.ndarray, w: np.ndarray) -> bool:
@@ -24,7 +29,8 @@ def dedup_fits(csrc: np.ndarray, cdst: np.ndarray, w: np.ndarray) -> bool:
         return False
     if int(np.abs(w).astype(np.int64).sum()) >= 2**31:
         return False
-    return seg_merge_vmem_bytes(csrc.size) <= VMEM_BUDGET_BYTES
+    return seg_merge_vmem_bytes(dedup_records(csrc, cdst)) <= \
+        VMEM_BUDGET_BYTES
 
 
 def dedup_arcs_fused(csrc: np.ndarray, cdst: np.ndarray, w: np.ndarray,
@@ -37,7 +43,7 @@ def dedup_arcs_fused(csrc: np.ndarray, cdst: np.ndarray, w: np.ndarray,
     if csrc.size == 0:
         return (csrc.astype(np.int64), cdst.astype(np.int64),
                 w.astype(np.int64))
-    L = max(2, _next_pow2(csrc.size))
+    L = padded_lanes(csrc.size)
     pad = L - csrc.size
     src32 = np.concatenate([csrc.astype(np.int32),
                             np.full(pad, I32_MAX, np.int32)])

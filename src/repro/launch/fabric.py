@@ -78,6 +78,7 @@ def _run_worker(args) -> int:
     # multi-process group first (no-op in single-process mode), then
     # host-device faking for multi-device meshes on CPU
     from repro.api import runtime
+    runtime.enable_compile_cache()
     info = runtime.distributed_init(
         coordinator_address=args.coordinator,
         num_processes=args.num_processes, process_id=args.process_id)

@@ -73,10 +73,11 @@ def main() -> int:
                     help="also print the per-level trace records")
     args = ap.parse_args()
 
-    # device forcing first — repro.api.runtime errors cleanly if some
-    # earlier import already initialized jax, instead of silently serving
-    # a stale device count.
+    # compile cache and device forcing first — repro.api.runtime errors
+    # cleanly if some earlier import already initialized jax, instead of
+    # silently serving a stale device count.
     from repro.api import runtime
+    runtime.enable_compile_cache()
     if args.devices:
         runtime.force_host_devices(args.devices)
 

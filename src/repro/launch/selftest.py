@@ -2,6 +2,9 @@
 
 Usage:  python -m repro.launch.selftest --devices 8 --test all
 
+A CPU tool: the devices are host devices forced into existence, never
+chips (``chip_smoke.py --four-chips`` is the check on a TPU host).
+
 Forces the device count through ``repro.api.runtime`` *before* any jax
 init (the count locks at first backend creation; the helper raises
 instead of silently misconfiguring), then validates the distributed
@@ -97,7 +100,7 @@ def main() -> int:
         def run(fn):
             f = shard_map(lambda s: fn(s[0])[None], mesh=mesh,
                           in_specs=PS("pe"), out_specs=PS("pe"),
-                          check_rep=True)
+                          check=True)
             return np.asarray(jax.jit(f)(jnp.asarray(slab)))
 
         out_direct = run(lambda s: direct_all_to_all(s, "pe"))
@@ -123,7 +126,7 @@ def main() -> int:
                     v[0], si[0], rs[0], n_ghost, "pe", P,
                     use_grid=use_grid)[None],
                 mesh=mesh, in_specs=(PS("pe"),) * 3, out_specs=PS("pe"),
-                check_rep=True)
+                check=True)
             return np.asarray(jax.jit(fn)(
                 jnp.asarray(vals), jnp.asarray(shards.send_idx),
                 jnp.asarray(shards.recv_slot)))

@@ -1,9 +1,14 @@
 """Serving-tier CLI — drive a multi-mesh ``PartitionServer``.
 
+  python -m repro.launch.serve --requests 16 --verify   # one mesh/device
   python -m repro.launch.serve --meshes 2 --devices-per-mesh 2 \
       --requests 12 --n 4000 --k 8
-  python -m repro.launch.serve --meshes 2 --requests 16 --verify
   python -m repro.launch.serve ... --offered-rate 8   # paced admission
+
+Without ``--meshes`` the server carves every device the host has into
+meshes of ``--devices-per-mesh``. An explicit ``--meshes`` with
+``--devices-per-mesh`` above 1 forces that many host (CPU) devices, a
+tool for running the multi-mesh tier on a machine without chips.
 
 Generates a mixed request set (sizes, k, single + distributed), serves
 it through the admission queue, prints one JSON summary line per
@@ -42,7 +47,9 @@ def build_requests(args):
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--meshes", type=int, default=2)
+    ap.add_argument("--meshes", type=int, default=None,
+                    help="worker meshes (default: as many as the host's "
+                         "devices fill)")
     ap.add_argument("--devices-per-mesh", type=int, default=1)
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--family", default="rgg2d")
@@ -56,10 +63,14 @@ def main() -> int:
                     help="assert bit-identity against solo runs")
     args = ap.parse_args()
 
-    # device forcing first, before any jax init (errors cleanly if an
-    # earlier import already initialized a backend)
+    # compile cache and device forcing first, before any jax init
+    # (forcing errors cleanly if an earlier import initialized a backend)
     from repro.api import runtime
-    if args.devices_per_mesh > 1:
+    runtime.enable_compile_cache()
+    if args.meshes is None:
+        args.meshes = max(
+            1, runtime.device_count() // args.devices_per_mesh)
+    elif args.devices_per_mesh > 1:
         runtime.force_host_devices(args.meshes * args.devices_per_mesh)
 
     from repro.serve import PartitionServer

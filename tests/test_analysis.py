@@ -24,14 +24,14 @@ def rules(report):
 # negative tests: the seeded fixtures must fire their pass
 # ---------------------------------------------------------------------------
 
-def test_collective_fixture_fires_mismatch_and_check_rep():
+def test_collective_fixture_fires_mismatch_and_unchecked():
     # P=1 is enough: the branch-signature mismatch and the
-    # check_rep=False staging are structural, not device-count-bound
+    # check=False staging are structural, not device-count-bound
     report = Report()
     collectives_pass.run(fixture_collective_mismatch.captured(1), report)
     got = rules(report)
     assert "SPMD002" in got, got   # cond branches diverge on psum
-    assert "SPMD003" in got, got   # check_rep=False, not allowlisted
+    assert "SPMD003" in got, got   # check=False, not allowlisted
 
 
 def test_overflow_fixture_fires_on_sum_form():
@@ -65,7 +65,7 @@ def test_lint_fixture_fires_all_three_rules():
     lint.check_file(fixture_lint.__file__, report, serve_hot=True)
     got = rules(report)
     assert got.count("LNT001") == 2, got  # np.random + random.random
-    assert "LNT002" in got, got           # shard_map w/o check_rep=
+    assert "LNT002" in got, got           # shard_map w/o check=
     assert "LNT003" in got, got           # .item() in serve hot path
 
 

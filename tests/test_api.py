@@ -5,6 +5,10 @@ Multi-device facade coverage lives in test_distributed.py (subprocess
 selftest ``--test api``); here the dist backends run at P=1 in-process.
 """
 
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -309,3 +313,37 @@ def test_force_host_devices_after_init():
     runtime.force_host_devices(1)       # enough devices -> no-op
     with pytest.raises(RuntimeError, match="already initialized"):
         runtime.force_host_devices(4096)
+
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _cache_probe(env_dir, code):
+    """First line a fresh process prints after ``enable_compile_cache``."""
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_COMPILATION_CACHE_DIR",
+                        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS")}
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "from repro.api import runtime\n"
+         "print(runtime.enable_compile_cache())\n" + code],
+        env=env, capture_output=True, text=True, timeout=120, check=True)
+    return out.stdout.strip().splitlines()[0]
+
+
+def test_compile_cache_written_to_env_dir(tmp_path):
+    got = _cache_probe(tmp_path, "import jax, jax.numpy as jnp\n"
+                       "jax.jit(lambda x: x * 3 + 1)(jnp.ones(5))"
+                       ".block_until_ready()")
+    assert got == str(tmp_path)
+    assert os.listdir(tmp_path), "nothing cached in JAX_COMPILATION_CACHE_DIR"
+
+
+def test_compile_cache_defaults_to_checkout():
+    assert _cache_probe(None, "") == os.path.join(ROOT, ".jax_cache")
+    with open(os.path.join(ROOT, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()

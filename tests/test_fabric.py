@@ -287,6 +287,48 @@ def test_per_worker_served_grows_for_late_workers():
 # runtime helpers (satellites)
 # ---------------------------------------------------------------------------
 
+def test_process_scaler_caps_chip_workers_and_keeps_their_logs(
+        tmp_path, monkeypatch):
+    from repro.fabric import autoscaler
+
+    started = []
+
+    class FakeWorker:  # stays alive until closed
+        pid = 4242
+
+        def __init__(self, cmd, env, stdout, stderr):
+            started.append((cmd, stdout.name, stderr))
+
+        def poll(self):
+            return None
+
+        def send_signal(self, sig):
+            pass
+
+        def wait(self, timeout=None):
+            return 0
+
+    monkeypatch.setattr(autoscaler.subprocess, "Popen", FakeWorker)
+    uncapped = autoscaler.ProcessScaler([], env={"JAX_PLATFORMS": "cpu"},
+                                        log_dir=str(tmp_path))
+    assert uncapped.worker_cap is None
+    # a host with a TPU: every worker would open all of its chips
+    monkeypatch.setattr(autoscaler, "_workers_hold_chips", lambda env: True)
+    scaler = autoscaler.ProcessScaler(["--frontdoor", "127.0.0.1:1"],
+                                      log_dir=str(tmp_path))
+    assert scaler.worker_cap == 1
+    sid = scaler.scale_up()
+    assert sid is not None
+    assert scaler.scale_up() is None      # the one chip slot is taken
+    assert scaler.count() == 1
+    cmd, log, err = started[0]
+    assert cmd[-2:] == ["--frontdoor", "127.0.0.1:1"]
+    assert log == str(tmp_path / f"{sid}.log")
+    assert err is subprocess.STDOUT
+    scaler.close()
+    assert scaler.count() == 0
+
+
 def test_device_slices_error_names_counts_and_feasible_carve():
     import jax
     have = len(jax.devices())
