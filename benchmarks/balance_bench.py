@@ -78,7 +78,7 @@ out["pipeline"] = {}
 for name, cfg in cfgs.items():
     res = Partitioner().run(PartitionRequest(
         graph=g, k=k, config=cfg, backend="dist-grid", devices=P))
-    unc = [t for t in res.trace if t["phase"] == "dist-uncoarsen"]
+    unc = [t for t in res.trace if t.get("phase") == "dist-uncoarsen"]
     out["pipeline"][name] = {
         "time_s": round(float(res.time_s), 4),
         "cut": res.cut, "feasible": res.feasible,

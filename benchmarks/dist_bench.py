@@ -48,8 +48,8 @@ for name, contraction, weights in (
     engine.run(req)       # discarded warmup: absorbs jit/Pallas compiles
     res = engine.run(req)  # steady state (same shapes, warm caches)
     levels = [t for t in res.trace
-              if t["phase"].startswith("dist-coarsen")]
-    unc = [t for t in res.trace if t["phase"] == "dist-uncoarsen"]
+              if t.get("phase", "").startswith("dist-coarsen")]
+    unc = [t for t in res.trace if t.get("phase") == "dist-uncoarsen"]
     # peak persistent replicated state per PE: the cluster weight table
     # of the largest level plus the block weight table (4-byte entries)
     def table_bytes(nl):

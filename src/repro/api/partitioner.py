@@ -7,6 +7,7 @@ from typing import Iterable, List, Optional, Sequence, Union
 
 import numpy as np
 
+from .. import spans
 from ..core import metrics
 from ..graphs.format import Graph
 from .backends import BackendContext, get_backend, resolve_backend
@@ -28,20 +29,29 @@ class Partitioner:
 
     def run(self, request: PartitionRequest, *,
             _ctx: Optional[BackendContext] = None) -> PartitionResult:
+        """Run one request. With ``collect_trace`` its trace gets the
+        per-level records and the spans of ``repro.spans`` under one
+        ``api.run`` root."""
         req = request
         if self.backend is not None and req.backend == "auto":
             req = dataclasses.replace(req, backend=self.backend)
         req.validate()
-        g = req.resolve_graph()
-        name = resolve_backend(req, g.n)
-        fn = get_backend(name)
         ctx = _ctx or BackendContext(devices=req.devices)
         if ctx.trace is None and req.collect_trace:
             ctx.trace = []
-        t0 = time.perf_counter()
-        assignment = np.asarray(fn(g, req, ctx), dtype=np.int64)
-        dt = time.perf_counter() - t0
-        s = metrics.summarize(g, assignment, req.k, req.epsilon)
+        with spans.recording(ctx.trace), \
+                spans.span("api.run", k=req.k) as root:
+            with spans.span("api.resolve_graph"):
+                g = req.resolve_graph()
+            name = resolve_backend(req, g.n)
+            root.set(backend=name, n=g.n, m=g.m)
+            fn = get_backend(name)
+            with spans.span("api.backend", backend=name):
+                t0 = time.perf_counter()
+                assignment = np.asarray(fn(g, req, ctx), dtype=np.int64)
+                dt = time.perf_counter() - t0
+            with spans.span("api.summarize"):
+                s = metrics.summarize(g, assignment, req.k, req.epsilon)
         s.update({"n": g.n, "m": g.m})
         return PartitionResult(assignment=assignment,
                                feasible=bool(s["feasible"]),

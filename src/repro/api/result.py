@@ -6,6 +6,7 @@ from typing import Any, Dict, Tuple
 
 import numpy as np
 
+from ..core.deep_mgp import level_records
 from .request import PartitionRequest
 
 
@@ -16,7 +17,8 @@ class PartitionResult:
     ``metrics`` is ``repro.core.metrics.summarize`` output plus the graph
     sizes ``n``/``m``; ``feasible`` mirrors its feasibility flag.
     ``trace`` holds one record per driver phase/level (sizes, cuts, wall
-    times) in execution order.
+    times) in execution order, with the span records and
+    ``kernel-fallback`` events of ``repro.spans`` among them.
     """
     assignment: np.ndarray          # (n,) int64 block ids
     feasible: bool
@@ -44,6 +46,6 @@ class PartitionResult:
             else self.backend,
             "time_s": round(float(self.time_s), 3),
             "devices": int(self.request.devices),
-            "levels": len(self.trace),
+            "levels": len(level_records(self.trace)),
         })
         return out

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import spans
 from ..graphs.format import Graph
 from ..kernels import dispatch
 from . import lp
@@ -174,70 +175,83 @@ def rebalance(g: Graph,
     n = g.n
     k = int(l_max_vec.shape[0])
     t_start = time.perf_counter()
-    from . import metrics
-    block_w = metrics.block_weights(g, part, k)
-    if not bool(np.any(block_w > l_max_vec)):
-        if stats is not None:
-            stats.update(rounds=0, gather_bytes=0,
-                         time_s=time.perf_counter() - t_start)
-        return np.array(part, dtype=np.int64)   # fresh array, never a view
-    # build_chunks raises a clear ValueError for totals >= 2^31 (the
-    # int32 jit path would wrap)
-    chunks = lp.build_chunks(g, 1)
-    n_pad = chunks.n_pad
-    top_m = min(top_m, n_pad + 1)
-    labels = np.zeros(n_pad + 1, dtype=np.int32)
-    labels[:n] = part
-    vw = np.zeros(n_pad + 1, dtype=np.int32)
-    vw[:n] = g.vweights
-    from .refinement import pad_blocks
-    bw_p, lv_p, pr_p, _ = pad_blocks(block_w, l_max_vec, parent)
-    labels = jnp.asarray(labels)
-    vw_j = jnp.asarray(vw)
-    block_w = jnp.asarray(bw_p)
-    l_max_j = jnp.asarray(lv_p)
-    parent_j = jnp.asarray(pr_p)
-    valid = jnp.asarray(np.arange(n_pad + 1) < n)
-    restricted = parent is not None
-    fused_ell = None
-    if dispatch.resolve_kernel_mode(kernel) == "fused":
-        from ..kernels.bal_round import ops as bal_ops
-        idx, ew = bal_ops.build_balance_ell(g, n_pad)
-        if bal_ops.balance_ell_fits(idx.shape[0], idx.shape[1],
-                                    restricted=restricted):
-            fused_ell = (jnp.asarray(idx), jnp.asarray(ew))
-        else:
-            dispatch.report_fallback(
-                "bal_round",
-                bal_ops.bal_scores_vmem_bytes(
-                    idx.shape[0], idx.shape[1], bal_ops.ROW_TILE,
-                    restricted=restricted),
-                detail="rebalance")
-    if fused_ell is None:
-        src = jnp.asarray(chunks.src[0])
-        dst = jnp.asarray(chunks.dst[0])
-        w = jnp.asarray(chunks.w[0])
-    rounds = 0
-    for r in range(max_rounds):
-        salt = jnp.uint32((seed * 7919 + r) % (2**32))
-        if fused_ell is not None:
+    with spans.span("level.balance", n=n, m=g.m, k=k) as sp:
+        with spans.span("level.feasibility"):
+            from . import metrics
+            block_w = metrics.block_weights(g, part, k)
+            feasible = not bool(np.any(block_w > l_max_vec))
+        if feasible:
+            sp.add("rounds", 0)
+            if stats is not None:
+                stats.update(rounds=0, gather_bytes=0,
+                             time_s=time.perf_counter() - t_start)
+            return np.array(part, dtype=np.int64)  # fresh, never a view
+        # build_chunks raises a clear ValueError for totals >= 2^31 (the
+        # int32 jit path would wrap)
+        with spans.span("level.slab_build"):
+            chunks = lp.build_chunks(g, 1)
+        n_pad = chunks.n_pad
+        top_m = min(top_m, n_pad + 1)
+        labels = np.zeros(n_pad + 1, dtype=np.int32)
+        labels[:n] = part
+        vw = np.zeros(n_pad + 1, dtype=np.int32)
+        vw[:n] = g.vweights
+        from .refinement import pad_blocks
+        bw_p, lv_p, pr_p, _ = pad_blocks(block_w, l_max_vec, parent)
+        sp.set(n_pad=n_pad, m_pad=chunks.src.shape[1], k_pad=bw_p.shape[0])
+        labels = spans.upload(labels)
+        vw_j = spans.upload(vw)
+        block_w = spans.upload(bw_p)
+        l_max_j = spans.upload(lv_p)
+        parent_j = spans.upload(pr_p)
+        valid = spans.upload(np.arange(n_pad + 1) < n)
+        restricted = parent is not None
+        fused_ell = None
+        if dispatch.resolve_kernel_mode(kernel) == "fused":
             from ..kernels.bal_round import ops as bal_ops
-            labels, block_w, overloaded = bal_ops.balance_round_fused(
-                labels, block_w, l_max_j, parent_j, fused_ell[0],
-                fused_ell[1], vw_j, valid, salt, n=n_pad, top_m=top_m,
-                restricted=restricted,
-                interpret=dispatch.kernel_interpret())
-        else:
-            labels, block_w, overloaded = balance_round(
-                labels, block_w, l_max_j, parent_j, src, dst, w, vw_j,
-                valid, salt, n=n_pad, top_m=top_m, restricted=restricted)
-        rounds = r + 1
-        if not bool(overloaded):
-            break
-    if stats is not None:
-        # the host balancer pays one O(m) single-chunk gather up front
-        stats.update(rounds=rounds,
-                     gather_bytes=int(chunks.src.nbytes + chunks.dst.nbytes
-                                      + chunks.w.nbytes),
-                     time_s=time.perf_counter() - t_start)
-    return np.asarray(labels)[:n].astype(np.int64)
+            with spans.span("level.ell_build", kernel="bal_round") as eb:
+                idx, ew = bal_ops.build_balance_ell(g, n_pad)
+                used = bal_ops.balance_ell_fits(idx.shape[0], idx.shape[1],
+                                                restricted=restricted)
+                eb.set(used=used, rows=idx.shape[0], lanes=idx.shape[1])
+            if used:
+                fused_ell = (spans.upload(idx), spans.upload(ew))
+            else:
+                dispatch.report_fallback(
+                    "bal_round",
+                    bal_ops.bal_scores_vmem_bytes(
+                        idx.shape[0], idx.shape[1], bal_ops.ROW_TILE,
+                        restricted=restricted),
+                    detail="rebalance")
+        if fused_ell is None:
+            src = spans.upload(chunks.src[0])
+            dst = spans.upload(chunks.dst[0])
+            w = spans.upload(chunks.w[0])
+        rounds = 0
+        with spans.span("level.iterate"):
+            for r in range(max_rounds):
+                salt = jnp.uint32((seed * 7919 + r) % (2**32))
+                if fused_ell is not None:
+                    labels, block_w, overloaded = \
+                        bal_ops.balance_round_fused(
+                            labels, block_w, l_max_j, parent_j,
+                            fused_ell[0], fused_ell[1], vw_j, valid, salt,
+                            n=n_pad, top_m=top_m, restricted=restricted,
+                            interpret=dispatch.kernel_interpret())
+                else:
+                    labels, block_w, overloaded = balance_round(
+                        labels, block_w, l_max_j, parent_j, src, dst, w,
+                        vw_j, valid, salt, n=n_pad, top_m=top_m,
+                        restricted=restricted)
+                rounds = r + 1
+                if not bool(spans.fetch(overloaded)):
+                    break
+        sp.add("rounds", rounds)
+        if stats is not None:
+            # the host balancer pays one O(m) single-chunk gather up front
+            stats.update(rounds=rounds,
+                         gather_bytes=int(chunks.src.nbytes
+                                          + chunks.dst.nbytes
+                                          + chunks.w.nbytes),
+                         time_s=time.perf_counter() - t_start)
+        return spans.fetch(labels)[:n].astype(np.int64)

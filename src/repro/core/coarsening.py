@@ -12,7 +12,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
-from ..graphs.format import Graph, degree_bucket_order, permute
+from .. import spans
+from ..graphs.format import Graph
 from ..kernels import dispatch
 from ..kernels.lp_move import ops as move_ops
 from . import lp
@@ -79,22 +80,21 @@ def cluster_prepare(g: Graph, num_chunks: int, seed: int,
     instead of arc slabs (falling back to arc slabs when the chunk
     working set would not fit the kernel's VMEM budget); both describe
     identical vertex ranges (``lp.chunk_bounds``)."""
-    n = g.n
-    rng = np.random.default_rng(seed)
-    order = degree_bucket_order(g, rng)
-    perm = np.empty(n, dtype=np.int64)
-    perm[order] = np.arange(n)
-    g2, _ = permute(g, perm)
+    perm, g2 = lp.reorder(g, seed)
     if kernel == "fused":
-        chunks = move_ops.build_move_chunks(g2, num_chunks)
-        if move_ops.move_chunks_fit_vmem(chunks):
+        with spans.span("level.ell_build", kernel="lp_move") as sp:
+            chunks = move_ops.build_move_chunks(g2, num_chunks)
+            used = move_ops.move_chunks_fit_vmem(chunks)
+            _, R, D = chunks.shape
+            sp.set(used=used, rows=R, lanes=D)
+        if used:
             return perm, g2, chunks
-        _, R, D = chunks.shape
         dispatch.report_fallback(
             "lp_move",
             move_ops.lp_move_vmem_bytes(R, D, move_ops.ROW_TILE),
             detail="cluster_prepare")
-    chunks = lp.build_chunks(g2, num_chunks)
+    with spans.span("level.slab_build"):
+        chunks = lp.build_chunks(g2, num_chunks)
     return perm, g2, chunks
 
 
@@ -109,9 +109,10 @@ def cluster_finish(labels_pad: np.ndarray, g2: Graph, perm: np.ndarray,
     vertices, exactly enforce the cluster-weight bound, and map the
     labels back to the input graph's vertex numbering."""
     n = g2.n
-    lab2 = np.asarray(labels_pad)[:n].astype(np.int64)
-    lab2 = enforce_cluster_weights(lab2, np.asarray(g2.vweights),
-                                   int(max_cluster_weight))
+    lab2 = spans.fetch(labels_pad)[:n].astype(np.int64)
+    with spans.span("level.enforce_weights"):
+        lab2 = enforce_cluster_weights(lab2, np.asarray(g2.vweights),
+                                       int(max_cluster_weight))
     return lab2[perm]
 
 
@@ -131,27 +132,35 @@ def cluster(g: Graph,
     if n <= 1:
         return np.zeros(n, dtype=np.int64)
     mode = dispatch.resolve_kernel_mode(kernel)
-    perm, g2, chunks = cluster_prepare(g, num_chunks, seed, kernel=mode)
-    np_pad = chunks.n_pad
-    labels = jnp.arange(np_pad + 1, dtype=jnp.int32)
-    vw = np.zeros(np_pad + 1, dtype=np.int32)
-    vw[:n] = g2.vweights
-    vw = jnp.asarray(vw)
-    cluster_w = vw
-    W = jnp.int32(max(1, max_cluster_weight))
-    if isinstance(chunks, move_ops.MoveChunks):
-        idx, cw_slab = jnp.asarray(chunks.idx), jnp.asarray(chunks.w)
-        v0s = jnp.asarray(chunks.v0)
-        interp = dispatch.kernel_interpret()
-        for it in range(num_iterations):
-            labels, cluster_w = move_ops.cluster_iteration_fused(
-                labels, cluster_w, idx, cw_slab, v0s, vw, W,
-                jnp.uint32(cluster_seed(seed, it)), n=np_pad,
-                interpret=interp)
-    else:
-        for it in range(num_iterations):
-            labels, cluster_w = lp.cluster_iteration(
-                labels, cluster_w, jnp.asarray(chunks.src),
-                jnp.asarray(chunks.dst), jnp.asarray(chunks.w), vw, W,
-                jnp.uint32(cluster_seed(seed, it)), n=np_pad)
-    return cluster_finish(labels, g2, perm, int(W))
+    with spans.span("level.cluster", n=n, m=g.m) as sp:
+        perm, g2, chunks = cluster_prepare(g, num_chunks, seed, kernel=mode)
+        np_pad = chunks.n_pad
+        fused = isinstance(chunks, move_ops.MoveChunks)
+        sp.set(n_pad=np_pad, kernel="fused" if fused else "composed")
+        if not fused:
+            sp.set(m_pad=chunks.src.shape[1])
+        labels = jnp.arange(np_pad + 1, dtype=jnp.int32)
+        vw = np.zeros(np_pad + 1, dtype=np.int32)
+        vw[:n] = g2.vweights
+        vw = spans.upload(vw)
+        cluster_w = vw
+        W = jnp.int32(max(1, max_cluster_weight))
+        with spans.span("level.iterate"):
+            if fused:
+                idx, cw_slab = (spans.upload(chunks.idx),
+                                spans.upload(chunks.w))
+                v0s = spans.upload(chunks.v0)
+                interp = dispatch.kernel_interpret()
+                for it in range(num_iterations):
+                    labels, cluster_w = move_ops.cluster_iteration_fused(
+                        labels, cluster_w, idx, cw_slab, v0s, vw, W,
+                        jnp.uint32(cluster_seed(seed, it)), n=np_pad,
+                        interpret=interp)
+            else:
+                for it in range(num_iterations):
+                    labels, cluster_w = lp.cluster_iteration(
+                        labels, cluster_w, spans.upload(chunks.src),
+                        spans.upload(chunks.dst), spans.upload(chunks.w),
+                        vw, W, jnp.uint32(cluster_seed(seed, it)),
+                        n=np_pad)
+        return cluster_finish(labels, g2, perm, int(W))

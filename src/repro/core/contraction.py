@@ -11,6 +11,7 @@ from typing import Tuple
 
 import numpy as np
 
+from .. import spans
 from ..graphs.format import Graph, from_coo
 from ..kernels import dispatch
 
@@ -28,6 +29,11 @@ def dedup_arcs(csrc: np.ndarray, cdst: np.ndarray, w: np.ndarray,
     kernel's int32/VMEM envelope, reported via
     ``dispatch.report_fallback``).
     """
+    with spans.span("level.dedup", arcs=int(csrc.size)):
+        return _dedup_arcs(csrc, cdst, w, kernel)
+
+
+def _dedup_arcs(csrc, cdst, w, kernel):
     if dispatch.resolve_kernel_mode(kernel) == "fused":
         from ..kernels.seg_merge import ops as seg_ops
         if seg_ops.dedup_fits(csrc, cdst, w):
@@ -59,13 +65,15 @@ def contract(g: Graph, labels: np.ndarray,
              kernel: str = "composed") -> Tuple[Graph, np.ndarray]:
     """Contract clustering ``labels`` (arbitrary ids). Returns
     (coarse_graph, fine_to_coarse) with fine_to_coarse[v] in [0, n_c)."""
-    uniq, cl = np.unique(labels, return_inverse=True)
-    nc = int(uniq.size)
-    cvw = np.zeros(nc, dtype=np.int64)
-    np.add.at(cvw, cl, g.vweights)
-    src = g.arc_tails()
-    csrc, cdst, w = dedup_arcs(cl[src], cl[g.adjncy], g.eweights,
-                               kernel=kernel)
-    gc = from_coo(nc, csrc, cdst, eweights=w, vweights=cvw,
-                  symmetrize=False, dedup=False)
+    with spans.span("level.contract", n=g.n, m=g.m) as sp:
+        uniq, cl = np.unique(labels, return_inverse=True)
+        nc = int(uniq.size)
+        cvw = np.zeros(nc, dtype=np.int64)
+        np.add.at(cvw, cl, g.vweights)
+        src = g.arc_tails()
+        csrc, cdst, w = dedup_arcs(cl[src], cl[g.adjncy], g.eweights,
+                                   kernel=kernel)
+        gc = from_coo(nc, csrc, cdst, eweights=w, vweights=cvw,
+                      symmetrize=False, dedup=False)
+        sp.set(coarse_n=nc, coarse_m=gc.m)
     return gc, cl.astype(np.int64)

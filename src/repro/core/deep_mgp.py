@@ -13,6 +13,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .. import spans
 from ..graphs.format import Graph, from_coo
 from . import metrics
 from .coarsening import cluster
@@ -108,6 +109,20 @@ def trace_event(trace: Optional[List[Dict]], **record) -> None:
         trace.append(record)
 
 
+def trace_cut(g: Graph, part: np.ndarray) -> int:
+    """The cut a per-level record reports: an O(m) pass that only tracing
+    runs, in a span of its own, after the record's ``time_s`` was taken."""
+    with spans.span("mgp.trace_cut", n=g.n, m=g.m):
+        return metrics.edge_cut(g, part)
+
+
+def level_records(trace) -> List[Dict]:
+    """The per-level phase records of a trace: no span records, events or
+    ``refine-mode`` annotations."""
+    return [r for r in trace
+            if "phase" in r and r["phase"] != "refine-mode"]
+
+
 def _refine_stats(cfg: "PartitionerConfig",
                   trace: Optional[List[Dict]]) -> Optional[Dict]:
     """A stats dict for ``balance_and_refine`` when the trace wants a
@@ -196,34 +211,37 @@ def extend_partition(g: Graph, part: np.ndarray, block_k: np.ndarray,
     restricted to siblings."""
     while block_k.shape[0] < target_blocks and np.any(block_k > 1):
         nb = block_k.shape[0]
-        graphs, ids = extract_block_subgraphs(g, part, nb)
+        with spans.span("extend.subgraphs", n=g.n, m=g.m, blocks=nb):
+            graphs, ids = extract_block_subgraphs(g, part, nb)
         new_part = np.empty(g.n, dtype=np.int64)
         new_counts: List[int] = []
         parent: List[int] = []
         off = 0
-        for b in range(nb):
-            if block_k[b] <= 1:
-                new_part[ids[b]] = off
-                new_counts.append(1)
-                parent.append(b)
-                off += 1
-                continue
-            k1, k2 = split_count(int(block_k[b]))
-            half = bipartition(graphs[b], k1, k2, l_final, rng,
-                               cfg.ip_repetitions)
-            new_part[ids[b]] = off + half
-            new_counts.extend([k1, k2])
-            parent.extend([b, b])
-            off += 2
+        with spans.span("extend.bipartition", blocks=nb) as sp:
+            for b in range(nb):
+                if block_k[b] <= 1:
+                    new_part[ids[b]] = off
+                    new_counts.append(1)
+                    parent.append(b)
+                    off += 1
+                    continue
+                k1, k2 = split_count(int(block_k[b]))
+                half = bipartition(graphs[b], k1, k2, l_final, rng,
+                                   cfg.ip_repetitions)
+                sp.add("bipartitions", 1)
+                new_part[ids[b]] = off + half
+                new_counts.extend([k1, k2])
+                parent.extend([b, b])
+                off += 2
         block_k = np.asarray(new_counts, dtype=np.int64)
         part = new_part
         # sibling-restricted refinement pass (cheap cleanup of the split)
         lv = _l_vec(block_k, l_final)
-        part = balance_and_refine(g, part, lv,
-                                  parent=np.asarray(parent, dtype=np.int64),
-                                  num_iterations=1,
-                                  num_chunks=cfg.num_chunks,
-                                  seed=cfg.seed + off, kernel=cfg.kernel)
+        with spans.span("extend.refine", n=g.n, m=g.m, blocks=off):
+            part = balance_and_refine(
+                g, part, lv, parent=np.asarray(parent, dtype=np.int64),
+                num_iterations=1, num_chunks=cfg.num_chunks,
+                seed=cfg.seed + off, kernel=cfg.kernel)
     return part, block_k
 
 
@@ -257,7 +275,9 @@ def partition(g: Graph, k: int, cfg: Optional[PartitionerConfig] = None,
     """Deep multilevel k-way partition. Returns block ids (n,).
 
     ``trace``, when given, receives one dict per phase/level (sizes, cuts,
-    wall times) — the structured log surfaced by ``repro.api``.
+    wall times) — the structured log surfaced by ``repro.api`` — plus
+    the span records and ``kernel-fallback`` events of ``repro.spans``
+    (recorded into ``trace`` unless a recorder already does).
 
     ``level0_labels``, when given, replaces the level-0 ``cluster`` call
     with precomputed labels. The caller guarantees they equal what that
@@ -270,6 +290,13 @@ def partition(g: Graph, k: int, cfg: Optional[PartitionerConfig] = None,
     check_k(k, "deep_mgp.partition")
     if k == 1 or g.n == 0:
         return np.zeros(g.n, dtype=np.int64)
+    with spans.recording(trace):
+        return _partition(g, k, cfg, trace, level0_labels)
+
+
+def _partition(g: Graph, k: int, cfg: PartitionerConfig,
+               trace: Optional[List[Dict]],
+               level0_labels: Optional[np.ndarray]) -> np.ndarray:
     rng = np.random.default_rng(cfg.seed)
     total_c = g.total_vweight
     max_c = int(g.vweights.max()) if g.n else 1
@@ -283,89 +310,93 @@ def partition(g: Graph, k: int, cfg: Optional[PartitionerConfig] = None,
     while G.n > C * min(k, K) and level < cfg.max_levels:
         kprime = max(1, min(k, G.n // max(1, C)))
         W = max(1, int(cfg.epsilon * total_c / kprime))
-        t0 = time.perf_counter()
-        if level == 0 and level0_labels is not None:
-            labels = np.asarray(level0_labels)
-            if labels.shape[0] != G.n:
-                raise ValueError(
-                    f"level0_labels has {labels.shape[0]} entries for a "
-                    f"{G.n}-vertex graph")
-        else:
-            labels = cluster(G, W, num_iterations=cfg.cluster_iterations,
-                             num_chunks=cfg.num_chunks, seed=cfg.seed + level,
-                             kernel=cfg.kernel)
-        Gc, mapping = contract(G, labels, kernel=cfg.kernel)
+        with spans.span("mgp.coarsen_level", level=level, n=G.n,
+                        m=G.m) as sp:
+            t0 = time.perf_counter()
+            if level == 0 and level0_labels is not None:
+                labels = np.asarray(level0_labels)
+                if labels.shape[0] != G.n:
+                    raise ValueError(
+                        f"level0_labels has {labels.shape[0]} entries for "
+                        f"a {G.n}-vertex graph")
+            else:
+                labels = cluster(G, W, num_iterations=cfg.cluster_iterations,
+                                 num_chunks=cfg.num_chunks,
+                                 seed=cfg.seed + level, kernel=cfg.kernel)
+            Gc, mapping = contract(G, labels, kernel=cfg.kernel)
+            dt = time.perf_counter() - t0
+            sp.set(coarse_n=Gc.n)
         log.info("level %d: n=%d -> n_c=%d (W=%d)", level, G.n, Gc.n, W)
         if Gc.n >= G.n * cfg.min_shrink:
             break  # converged — coarsest level reached
         trace_event(trace, phase="coarsen", level=level, n=G.n, m=G.m,
-                    coarse_n=Gc.n, W=W,
-                    time_s=round(time.perf_counter() - t0, 6))
+                    coarse_n=Gc.n, W=W, time_s=round(dt, 6))
         hierarchy.append((G, mapping))
         G = Gc
         level += 1
 
     # ---- initial partition of the coarsest graph (base case) -----------
-    t0 = time.perf_counter()
-    k0 = max(1, min(k, K))
-    counts = distribute_counts(k, k0)
-    part = partition_into_counts(G, counts, l_final, rng,
-                                 cfg.ip_repetitions)
-    block_k = np.asarray(counts, dtype=np.int64)
-    ref_stats = _refine_stats(cfg, trace)
-    part = balance_and_refine(G, part, _l_vec(block_k, l_final),
-                              num_iterations=cfg.refine_iterations,
-                              num_chunks=cfg.num_chunks, seed=cfg.seed,
-                              kernel=cfg.kernel, refine=cfg.refine,
-                              stats=ref_stats)
+    with spans.span("mgp.initial", n=G.n, m=G.m):
+        t0 = time.perf_counter()
+        k0 = max(1, min(k, K))
+        counts = distribute_counts(k, k0)
+        part = partition_into_counts(G, counts, l_final, rng,
+                                     cfg.ip_repetitions)
+        block_k = np.asarray(counts, dtype=np.int64)
+        ref_stats = _refine_stats(cfg, trace)
+        part = balance_and_refine(G, part, _l_vec(block_k, l_final),
+                                  num_iterations=cfg.refine_iterations,
+                                  num_chunks=cfg.num_chunks, seed=cfg.seed,
+                                  kernel=cfg.kernel, refine=cfg.refine,
+                                  stats=ref_stats)
+        dt = time.perf_counter() - t0
     if trace is not None:
         trace_event(trace, phase="initial", n=G.n, m=G.m,
-                    blocks=int(block_k.shape[0]),
-                    cut=metrics.edge_cut(G, part),
-                    time_s=round(time.perf_counter() - t0, 6))
+                    blocks=int(block_k.shape[0]), cut=trace_cut(G, part),
+                    time_s=round(dt, 6))
         _trace_refine_mode(trace, cfg, "initial", None, ref_stats)
 
     # ---- uncoarsening: project, extend, refine (lines 7–9, 13–18) ------
     for lvl, (Gf, mapping) in enumerate(reversed(hierarchy)):
-        t0 = time.perf_counter()
-        part = part[mapping]
-        target = min(k, ceil2(max(1, Gf.n // max(1, C))))
-        target = max(target, block_k.shape[0])
-        part, block_k = extend_partition(Gf, part, block_k, k, l_final,
-                                         cfg, rng, target)
-        ref_stats = _refine_stats(cfg, trace)
-        part = balance_and_refine(Gf, part, _l_vec(block_k, l_final),
-                                  num_iterations=cfg.refine_iterations,
-                                  num_chunks=cfg.num_chunks,
-                                  seed=uncoarsen_seed(cfg.seed, lvl),
-                                  kernel=cfg.kernel, refine=cfg.refine,
-                                  stats=ref_stats)
+        with spans.span("mgp.uncoarsen_level", level=lvl, n=Gf.n, m=Gf.m):
+            t0 = time.perf_counter()
+            part = part[mapping]
+            target = min(k, ceil2(max(1, Gf.n // max(1, C))))
+            target = max(target, block_k.shape[0])
+            part, block_k = extend_partition(Gf, part, block_k, k, l_final,
+                                             cfg, rng, target)
+            ref_stats = _refine_stats(cfg, trace)
+            part = balance_and_refine(Gf, part, _l_vec(block_k, l_final),
+                                      num_iterations=cfg.refine_iterations,
+                                      num_chunks=cfg.num_chunks,
+                                      seed=uncoarsen_seed(cfg.seed, lvl),
+                                      kernel=cfg.kernel, refine=cfg.refine,
+                                      stats=ref_stats)
+            dt = time.perf_counter() - t0
         if trace is not None:
             trace_event(trace, phase="uncoarsen", level=lvl, n=Gf.n,
                         m=Gf.m, blocks=int(block_k.shape[0]),
-                        cut=metrics.edge_cut(Gf, part),
-                        time_s=round(time.perf_counter() - t0, 6))
+                        cut=trace_cut(Gf, part), time_s=round(dt, 6))
             _trace_refine_mode(trace, cfg, "uncoarsen", lvl, ref_stats)
 
     # ---- final extension to exactly k blocks (omitted-case in Alg. 1) --
-    t0 = time.perf_counter()
-    part, block_k = extend_partition(g, part, block_k, k, l_final, cfg,
-                                     rng, target_blocks=k)
-    if block_k.shape[0] < k:  # blocks that cannot split further (tiny n)
-        pad = k - block_k.shape[0]
-        block_k = np.concatenate([block_k, np.ones(pad, dtype=np.int64)])
-    ref_stats = _refine_stats(cfg, trace)
-    part = balance_and_refine(g, part, np.full(k, l_final, dtype=np.int64),
-                              num_iterations=cfg.refine_iterations,
-                              num_chunks=cfg.num_chunks, seed=cfg.seed + 17,
-                              kernel=cfg.kernel, refine=cfg.refine,
-                              stats=ref_stats)
+    with spans.span("mgp.final", n=g.n, m=g.m):
+        t0 = time.perf_counter()
+        part, block_k = extend_partition(g, part, block_k, k, l_final, cfg,
+                                         rng, target_blocks=k)
+        if block_k.shape[0] < k:  # blocks that cannot split further
+            pad = k - block_k.shape[0]
+            block_k = np.concatenate([block_k, np.ones(pad, dtype=np.int64)])
+        ref_stats = _refine_stats(cfg, trace)
+        part = balance_and_refine(g, part,
+                                  np.full(k, l_final, dtype=np.int64),
+                                  num_iterations=cfg.refine_iterations,
+                                  num_chunks=cfg.num_chunks,
+                                  seed=cfg.seed + 17, kernel=cfg.kernel,
+                                  refine=cfg.refine, stats=ref_stats)
+        dt = time.perf_counter() - t0
     if trace is not None:
         trace_event(trace, phase="final", n=g.n, m=g.m, blocks=k,
-                    cut=metrics.edge_cut(g, part),
-                    time_s=round(time.perf_counter() - t0, 6))
+                    cut=trace_cut(g, part), time_s=round(dt, 6))
         _trace_refine_mode(trace, cfg, "final", None, ref_stats)
-    from ..kernels import dispatch
-    for rec in dispatch.drain_fallback_records():
-        trace_event(trace, **rec)
     return part

@@ -25,7 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..graphs.format import Graph
+from .. import spans
+from ..graphs.format import Graph, degree_bucket_order, permute
 
 I32_MAX = np.int32(np.iinfo(np.int32).max)
 
@@ -68,6 +69,18 @@ def chunk_bounds(g: Graph, num_chunks: int) -> list:
         bounds.append(min(max(v, bounds[-1]), n))
     bounds.append(n)
     return bounds
+
+
+def reorder(g: Graph, seed: int):
+    """Seeded degree-bucket iteration order (paper §4): ``(perm, g2)``,
+    ``g2`` the graph relabelled so that vertex ``v`` becomes ``perm[v]``."""
+    with spans.span("level.reorder"):
+        rng = np.random.default_rng(seed)
+        order = degree_bucket_order(g, rng)
+        perm = np.empty(g.n, dtype=np.int64)
+        perm[order] = np.arange(g.n)
+        g2, _ = permute(g, perm)
+    return perm, g2
 
 
 def build_chunks(g: Graph, num_chunks: int, pad_shapes: bool = True) -> LPChunks:

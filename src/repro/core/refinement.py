@@ -15,7 +15,8 @@ from typing import Dict, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from ..graphs.format import Graph, degree_bucket_order, permute
+from .. import spans
+from ..graphs.format import Graph
 from . import balance as bal
 from . import lp
 
@@ -75,36 +76,36 @@ def lp_refine(g: Graph,
     k = int(l_max_vec.shape[0])
     if n == 0 or k <= 1:
         return part
-    rng = np.random.default_rng(seed)
-    order = degree_bucket_order(g, rng)
-    perm = np.empty(n, dtype=np.int64)
-    perm[order] = np.arange(n)
-    g2, _ = permute(g, perm)
-    part2 = np.empty(n, dtype=np.int64)
-    part2[perm] = part  # part2[new_id] = part[old_id]
-    chunks = lp.build_chunks(g2, num_chunks)
-    n_pad = chunks.n_pad
-    labels = np.zeros(n_pad + 1, dtype=np.int32)
-    labels[:n] = part2
-    vw = np.zeros(n_pad + 1, dtype=np.int32)
-    vw[:n] = g2.vweights
-    block_w = np.zeros(k, dtype=np.int64)
-    np.add.at(block_w, part, g.vweights)
-    bw_p, lv_p, pr_p, _ = pad_blocks(block_w, l_max_vec, parent)
-    labels = jnp.asarray(labels)
-    vw_j = jnp.asarray(vw)
-    block_w = jnp.asarray(bw_p)
-    l_max_j = jnp.asarray(lv_p)
-    parent_j = jnp.asarray(pr_p)
-    restricted = parent is not None
-    for it in range(num_iterations):
-        labels, block_w = lp.refine_iteration(
-            labels, block_w, l_max_j, parent_j,
-            jnp.asarray(chunks.src), jnp.asarray(chunks.dst),
-            jnp.asarray(chunks.w), vw_j,
-            jnp.uint32((seed * 2654435761 + it) % (2**32)), n=n_pad,
-            restricted=restricted)
-    out2 = np.asarray(labels)[:n].astype(np.int64)
+    with spans.span("level.refine", n=n, m=g.m, k=k) as sp:
+        perm, g2 = lp.reorder(g, seed)
+        part2 = np.empty(n, dtype=np.int64)
+        part2[perm] = part  # part2[new_id] = part[old_id]
+        with spans.span("level.slab_build"):
+            chunks = lp.build_chunks(g2, num_chunks)
+        n_pad = chunks.n_pad
+        labels = np.zeros(n_pad + 1, dtype=np.int32)
+        labels[:n] = part2
+        vw = np.zeros(n_pad + 1, dtype=np.int32)
+        vw[:n] = g2.vweights
+        block_w = np.zeros(k, dtype=np.int64)
+        np.add.at(block_w, part, g.vweights)
+        bw_p, lv_p, pr_p, _ = pad_blocks(block_w, l_max_vec, parent)
+        sp.set(n_pad=n_pad, m_pad=chunks.src.shape[1], k_pad=bw_p.shape[0])
+        labels = spans.upload(labels)
+        vw_j = spans.upload(vw)
+        block_w = spans.upload(bw_p)
+        l_max_j = spans.upload(lv_p)
+        parent_j = spans.upload(pr_p)
+        restricted = parent is not None
+        with spans.span("level.iterate"):
+            for it in range(num_iterations):
+                labels, block_w = lp.refine_iteration(
+                    labels, block_w, l_max_j, parent_j,
+                    spans.upload(chunks.src), spans.upload(chunks.dst),
+                    spans.upload(chunks.w), vw_j,
+                    jnp.uint32((seed * 2654435761 + it) % (2**32)),
+                    n=n_pad, restricted=restricted)
+        out2 = spans.fetch(labels)[:n].astype(np.int64)
     return out2[perm]  # back to original ids: part[old] = out2[perm[old]]
 
 

@@ -34,7 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..graphs.format import Graph, degree_bucket_order, permute
+from .. import spans
+from ..graphs.format import Graph
 from . import lp
 from .lp import I32_MAX, _argmax_target, _group_conns, _own_connection
 
@@ -138,36 +139,37 @@ def unconstrained_refine(g: Graph,
         stats["penalty"] = penalty_schedule(num_iterations)
     if n == 0 or k <= 1 or num_iterations < 1:
         return part
-    rng = np.random.default_rng(seed)
-    order = degree_bucket_order(g, rng)
-    perm = np.empty(n, dtype=np.int64)
-    perm[order] = np.arange(n)
-    g2, _ = permute(g, perm)
-    part2 = np.empty(n, dtype=np.int64)
-    part2[perm] = part
-    chunks = lp.build_chunks(g2, num_chunks)
-    n_pad = chunks.n_pad
-    labels = np.zeros(n_pad + 1, dtype=np.int32)
-    labels[:n] = part2
-    vw = np.zeros(n_pad + 1, dtype=np.int32)
-    vw[:n] = g2.vweights
-    block_w = np.zeros(k, dtype=np.int64)
-    np.add.at(block_w, part, g.vweights)
-    from .refinement import pad_blocks   # deferred: refinement imports us
-    bw_p, lv_p, pr_p, _ = pad_blocks(block_w, l_max_vec, parent)
-    labels = jnp.asarray(labels)
-    vw_j = jnp.asarray(vw)
-    block_w = jnp.asarray(bw_p)
-    l_max_j = jnp.asarray(lv_p)
-    parent_j = jnp.asarray(pr_p)
-    restricted = parent is not None
-    pen_den = jnp.int32(num_iterations)
-    for it in range(num_iterations):
-        labels, block_w = urefine_iteration(
-            labels, block_w, l_max_j, parent_j,
-            jnp.asarray(chunks.src), jnp.asarray(chunks.dst),
-            jnp.asarray(chunks.w), vw_j,
-            jnp.uint32((seed * 2654435761 + it) % (2**32)),
-            jnp.int32(it), pen_den, n=n_pad, restricted=restricted)
-    out2 = np.asarray(labels)[:n].astype(np.int64)
+    with spans.span("level.refine", n=n, m=g.m, k=k,
+                    mode="unconstrained") as sp:
+        perm, g2 = lp.reorder(g, seed)
+        part2 = np.empty(n, dtype=np.int64)
+        part2[perm] = part
+        with spans.span("level.slab_build"):
+            chunks = lp.build_chunks(g2, num_chunks)
+        n_pad = chunks.n_pad
+        labels = np.zeros(n_pad + 1, dtype=np.int32)
+        labels[:n] = part2
+        vw = np.zeros(n_pad + 1, dtype=np.int32)
+        vw[:n] = g2.vweights
+        block_w = np.zeros(k, dtype=np.int64)
+        np.add.at(block_w, part, g.vweights)
+        from .refinement import pad_blocks  # deferred: refinement imports us
+        bw_p, lv_p, pr_p, _ = pad_blocks(block_w, l_max_vec, parent)
+        sp.set(n_pad=n_pad, m_pad=chunks.src.shape[1], k_pad=bw_p.shape[0])
+        labels = spans.upload(labels)
+        vw_j = spans.upload(vw)
+        block_w = spans.upload(bw_p)
+        l_max_j = spans.upload(lv_p)
+        parent_j = spans.upload(pr_p)
+        restricted = parent is not None
+        pen_den = jnp.int32(num_iterations)
+        with spans.span("level.iterate"):
+            for it in range(num_iterations):
+                labels, block_w = urefine_iteration(
+                    labels, block_w, l_max_j, parent_j,
+                    spans.upload(chunks.src), spans.upload(chunks.dst),
+                    spans.upload(chunks.w), vw_j,
+                    jnp.uint32((seed * 2654435761 + it) % (2**32)),
+                    jnp.int32(it), pen_den, n=n_pad, restricted=restricted)
+        out2 = spans.fetch(labels)[:n].astype(np.int64)
     return out2[perm]

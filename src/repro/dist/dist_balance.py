@@ -58,6 +58,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as PS
 
+from .. import spans
 from ..core.balance import balance_gains, greedy_select
 from ..core.lp import I32_MAX
 from ..graphs.distribute import GraphShards
@@ -242,29 +243,29 @@ def dist_rebalance(shards: GraphShards,
                                  shards.n_ghost, top_m_loc, use_grid,
                                  owner, fused=fused,
                                  interpret=dispatch.kernel_interpret())
-    lab_loc = jnp.asarray(lab_loc)
-    lab_ghost = jnp.asarray(lab_ghost)
-    bw_state = jnp.asarray(bw_state)
-    slab_args = (jnp.asarray(ell_idx), jnp.asarray(ell_w)) if fused else \
-        (jnp.asarray(shards.arc_src),
-         jnp.asarray(shards.arc_dst_idx),
-         jnp.asarray(shards.arc_w))
-    graph_args = slab_args + (jnp.asarray(shards.vweights),
-                  jnp.asarray(shards.local_gid),
-                  jnp.asarray(shards.send_idx),
-                  jnp.asarray(shards.recv_slot),
-                  jnp.asarray(shards.offsets.astype(np.int32)),
-                  jnp.asarray(lmax_dense))
+    lab_loc = spans.upload(lab_loc)
+    lab_ghost = spans.upload(lab_ghost)
+    bw_state = spans.upload(bw_state)
+    slab_args = (spans.upload(ell_idx), spans.upload(ell_w)) if fused else \
+        (spans.upload(shards.arc_src),
+         spans.upload(shards.arc_dst_idx),
+         spans.upload(shards.arc_w))
+    graph_args = slab_args + (spans.upload(shards.vweights),
+                  spans.upload(shards.local_gid),
+                  spans.upload(shards.send_idx),
+                  spans.upload(shards.recv_slot),
+                  spans.upload(shards.offsets.astype(np.int32)),
+                  spans.upload(lmax_dense))
     rounds = 0
     for r in range(max_rounds):
         lab_loc, lab_ghost, bw_state, overloaded = fn(
             lab_loc, lab_ghost, bw_state, *graph_args,
             jnp.uint32((seed * 7919 + r) % (2**32)))
         rounds = r + 1
-        if not bool(np.any(np.asarray(overloaded))):
+        if not bool(np.any(spans.fetch(overloaded))):
             break
 
-    lab = np.asarray(lab_loc)
+    lab = spans.fetch(lab_loc)
     out = np.empty(n, dtype=np.int64)
     out[shards.local_gid[valid]] = lab[valid]
     if stats is not None:
@@ -382,15 +383,15 @@ def dist_enforce_cluster_weights(shards: GraphShards,
     lab_loc = lab_pad[np.minimum(shards.local_gid, n)].astype(np.int32)
     fn = _build_enforce_fn(mesh, P, n, shards.n_loc, use_grid)
     out_loc, ejected = fn(
-        jnp.asarray(lab_loc), jnp.asarray(shards.vweights),
-        jnp.asarray(shards.local_gid),
+        spans.upload(lab_loc), spans.upload(shards.vweights),
+        spans.upload(shards.local_gid),
         jnp.int32(max(1, min(int(max_weight), int(I32_MAX)))))
-    out_loc = np.asarray(out_loc)
+    out_loc = spans.fetch(out_loc)
     valid = shards.local_gid < n
     out = np.empty(n, dtype=np.int64)
     out[shards.local_gid[valid]] = out_loc[valid]
     if stats is not None:
-        stats.update(ejected=int(np.asarray(ejected).sum()),
+        stats.update(ejected=int(spans.fetch(ejected).sum()),
                      slab_bytes_per_pe=int(P * shards.n_loc * 12),
                      time_s=time.perf_counter() - t0)
     return out

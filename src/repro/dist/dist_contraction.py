@@ -39,6 +39,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as PS
 
+from .. import spans
 from ..core.contraction import dedup_arcs
 from ..core.lp import I32_MAX
 from ..graphs.distribute import GraphShards, assemble_shards
@@ -191,8 +192,8 @@ def dist_contract(shards: GraphShards,
                                  detail="dist_contract")
     fn = _build_exchange_fn(mesh, P, S_e, use_grid, fused=fused,
                             interpret=dispatch.kernel_interpret())
-    s_src, s_dst, wsum, first = (np.asarray(x) for x in fn(
-        jnp.asarray(slab), jnp.asarray(seg_counts)))
+    s_src, s_dst, wsum, first = (spans.fetch(x) for x in fn(
+        spans.upload(slab), spans.upload(seg_counts)))
     exchange_s = time.perf_counter() - t0
 
     # ---- owner-side coarse shards + host view --------------------------

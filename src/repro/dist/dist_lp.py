@@ -45,6 +45,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as PS
 
+from .. import spans
 from ..core.lp import (I32_MAX, _argmax_target, _group_conns, _hash32,
                        _own_connection)
 from ..graphs.distribute import GraphShards, chunk_local_arcs
@@ -421,24 +422,24 @@ def dist_cluster(shards: GraphShards,
                                      detail="dist_cluster")
             fused = False
         else:
-            slabs = (jnp.asarray(idx), jnp.asarray(ws_ell),
-                     jnp.asarray(v0s))
+            slabs = (spans.upload(idx), spans.upload(ws_ell),
+                     spans.upload(v0s))
     if not fused:
         srcs, dsts, ws = chunk_local_arcs(shards, num_chunks)
         B = srcs.shape[1]
-        slabs = (jnp.asarray(srcs), jnp.asarray(dsts), jnp.asarray(ws))
+        slabs = (spans.upload(srcs), spans.upload(dsts), spans.upload(ws))
     fn = _build_cluster_fn(mesh, P, n, shards.n_loc, shards.n_ghost, B,
                            num_iterations, use_grid, owner, fused=fused,
                            interpret=dispatch.kernel_interpret())
     salts = (np.arange(num_iterations * B, dtype=np.uint64).reshape(
         num_iterations, B) * 0x85EBCA6B + seed * 1000003) % (2**32)
     lab = fn(*slabs,
-             jnp.asarray(shards.vweights), jnp.asarray(shards.local_gid),
-             jnp.asarray(shards.ghost_gid), jnp.asarray(shards.send_idx),
-             jnp.asarray(shards.recv_slot),
-             jnp.asarray(salts.astype(np.uint32)),
+             spans.upload(shards.vweights), spans.upload(shards.local_gid),
+             spans.upload(shards.ghost_gid), spans.upload(shards.send_idx),
+             spans.upload(shards.recv_slot),
+             spans.upload(salts.astype(np.uint32)),
              jnp.int32(max(1, min(int(max_cluster_weight), int(_BIG)))))
-    lab = np.asarray(lab)
+    lab = spans.fetch(lab)
     out = np.empty(n, dtype=np.int64)
     valid = shards.local_gid < n
     out[shards.local_gid[valid]] = lab[valid]
@@ -554,12 +555,12 @@ def dist_lp_refine(shards: GraphShards,
     salts = (np.arange(num_iterations * B, dtype=np.uint64).reshape(
         num_iterations, B) * 0xC2B2AE35 + seed * 2654435761) % (2**32)
     lmax32 = np.minimum(l_max_vec, int(_BIG)).astype(np.int32)
-    lab = fn(jnp.asarray(srcs), jnp.asarray(dsts), jnp.asarray(ws),
-             jnp.asarray(shards.vweights), jnp.asarray(part_loc),
-             jnp.asarray(part_ghost), jnp.asarray(shards.send_idx),
-             jnp.asarray(shards.recv_slot),
-             jnp.asarray(salts.astype(np.uint32)), jnp.asarray(lmax32))
-    lab = np.asarray(lab)
+    lab = fn(spans.upload(srcs), spans.upload(dsts), spans.upload(ws),
+             spans.upload(shards.vweights), spans.upload(part_loc),
+             spans.upload(part_ghost), spans.upload(shards.send_idx),
+             spans.upload(shards.recv_slot),
+             spans.upload(salts.astype(np.uint32)), spans.upload(lmax32))
+    lab = spans.fetch(lab)
     out = np.empty(n, dtype=np.int64)
     valid = shards.local_gid < n
     out[shards.local_gid[valid]] = lab[valid]
@@ -679,12 +680,12 @@ def dist_ulp_refine(shards: GraphShards,
     salts = (np.arange(num_iterations * B, dtype=np.uint64).reshape(
         num_iterations, B) * 0xC2B2AE35 + seed * 2654435761) % (2**32)
     lmax32 = np.minimum(l_max_vec, int(_BIG)).astype(np.int32)
-    lab = fn(jnp.asarray(srcs), jnp.asarray(dsts), jnp.asarray(ws),
-             jnp.asarray(shards.vweights), jnp.asarray(part_loc),
-             jnp.asarray(part_ghost), jnp.asarray(shards.send_idx),
-             jnp.asarray(shards.recv_slot),
-             jnp.asarray(salts.astype(np.uint32)), jnp.asarray(lmax32))
-    lab = np.asarray(lab)
+    lab = fn(spans.upload(srcs), spans.upload(dsts), spans.upload(ws),
+             spans.upload(shards.vweights), spans.upload(part_loc),
+             spans.upload(part_ghost), spans.upload(shards.send_idx),
+             spans.upload(shards.recv_slot),
+             spans.upload(salts.astype(np.uint32)), spans.upload(lmax32))
+    lab = spans.fetch(lab)
     out = np.empty(n, dtype=np.int64)
     valid = shards.local_gid < n
     out[shards.local_gid[valid]] = lab[valid]

@@ -27,13 +27,14 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from .. import spans
 from ..core import metrics
 from ..core.balance import rebalance
 from ..core.coarsening import enforce_cluster_weights
 from ..core.contraction import contract
 from ..core.deep_mgp import (PartitionerConfig, check_k,
-                             partition as sp_partition, trace_event,
-                             uncoarsen_seed)
+                             partition as sp_partition, trace_cut,
+                             trace_event, uncoarsen_seed)
 from ..graphs.distribute import GraphShards, distribute_graph
 from ..graphs.format import Graph
 from .dist_balance import dist_enforce_cluster_weights, dist_rebalance
@@ -78,7 +79,8 @@ def dist_refine_and_balance(g: Graph,
     part = np.asarray(part, dtype=np.int64)
     l_max_vec = np.asarray(l_max_vec, dtype=np.int64)
     if shards is None:
-        shards = distribute_graph(g, P)
+        with spans.span("dist.distribute", n=g.n, m=g.m, P=P):
+            shards = distribute_graph(g, P)
     if refine == "unconstrained":
         part = dist_ulp_refine(shards, part, l_max_vec,
                                num_iterations=num_iterations,
@@ -97,8 +99,9 @@ def dist_refine_and_balance(g: Graph,
                               weights=weights, kernel=kernel,
                               stats=balance_stats)
     else:
-        part = rebalance(g, part, l_max_vec, seed=seed + 1, kernel=kernel,
-                         stats=balance_stats)
+        with spans.span("dist.gather", step="balance", n=g.n, m=g.m):
+            part = rebalance(g, part, l_max_vec, seed=seed + 1,
+                             kernel=kernel, stats=balance_stats)
     return part
 
 
@@ -123,6 +126,13 @@ def dist_partition_impl(g: Graph,
         raise ValueError(f"dist_partition: P must be >= 1, got {P}")
     if k == 1 or g.n == 0:
         return np.zeros(g.n, dtype=np.int64)
+    with spans.recording(trace):
+        return _dist_partition(g, k, P, cfg, use_grid, mesh, trace)
+
+
+def _dist_partition(g: Graph, k: int, P: int, cfg: PartitionerConfig,
+                    use_grid: bool, mesh, trace: Optional[List[Dict]]
+                    ) -> np.ndarray:
     total_c = g.total_vweight
     l_final = metrics.l_max(total_c, k, cfg.epsilon,
                             int(g.vweights.max()) if g.n else 1)
@@ -138,43 +148,50 @@ def dist_partition_impl(g: Graph,
     while G.n > C * min(k, K) and G.n >= 2 * P and level < cfg.max_levels:
         kprime = max(1, min(k, G.n // max(1, C)))
         W = max(1, int(cfg.epsilon * total_c / kprime))
-        t0 = time.perf_counter()
-        if shards is None:  # sharded contraction hands us the next level
-            shards = distribute_graph(G, P)
-        labels = dist_cluster(shards, W,
-                              num_iterations=cfg.cluster_iterations,
-                              num_chunks=cfg.num_chunks,
-                              seed=cfg.seed + level, use_grid=use_grid,
-                              mesh=mesh, weights=cfg.weights,
-                              kernel=cfg.kernel)
-        if cfg.balance == "dist":
-            # coarsening-side balancing stays sharded: the exact
-            # eject-to-singleton sweep runs owner-side instead of
-            # round-tripping the labels through host numpy
-            labels = dist_enforce_cluster_weights(
-                shards, labels, W, use_grid=use_grid, mesh=mesh)
-        else:
-            labels = enforce_cluster_weights(labels,
-                                             np.asarray(G.vweights), W)
-        if cfg.contraction == "sharded":
-            res = dist_contract(shards, labels, use_grid=use_grid,
-                                mesh=mesh, kernel=cfg.kernel)
-            Gc, mapping, next_shards = res.graph, res.mapping, res.shards
-            cstats = res.stats
-        else:
-            Gc, mapping = contract(G, labels, kernel=cfg.kernel)
-            next_shards, cstats = None, None
+        with spans.span("dist.coarsen_level", level=level, n=G.n, m=G.m,
+                        P=P) as sp:
+            t0 = time.perf_counter()
+            if shards is None:  # sharded contraction hands us the next level
+                with spans.span("dist.distribute", n=G.n, m=G.m, P=P):
+                    shards = distribute_graph(G, P)
+            labels = dist_cluster(shards, W,
+                                  num_iterations=cfg.cluster_iterations,
+                                  num_chunks=cfg.num_chunks,
+                                  seed=cfg.seed + level, use_grid=use_grid,
+                                  mesh=mesh, weights=cfg.weights,
+                                  kernel=cfg.kernel)
+            if cfg.balance == "dist":
+                # coarsening-side balancing stays sharded: the exact
+                # eject-to-singleton sweep runs owner-side instead of
+                # round-tripping the labels through host numpy
+                labels = dist_enforce_cluster_weights(
+                    shards, labels, W, use_grid=use_grid, mesh=mesh)
+            else:
+                with spans.span("dist.gather", step="enforce", n=G.n):
+                    labels = enforce_cluster_weights(
+                        labels, np.asarray(G.vweights), W)
+            if cfg.contraction == "sharded":
+                res = dist_contract(shards, labels, use_grid=use_grid,
+                                    mesh=mesh, kernel=cfg.kernel)
+                Gc, mapping, next_shards = res.graph, res.mapping, res.shards
+                cstats = res.stats
+            else:
+                with spans.span("dist.gather", step="contract", n=G.n,
+                                m=G.m):
+                    Gc, mapping = contract(G, labels, kernel=cfg.kernel)
+                next_shards, cstats = None, None
+            dt = time.perf_counter() - t0
+            sp.set(coarse_n=Gc.n)
         if Gc.n >= G.n * cfg.min_shrink:
             # converged — coarsest distributed level reached; record the
             # discarded level so benchmark traces explain the early exit
             trace_event(trace, phase="dist-coarsen-converged", level=level,
                         n=G.n, m=G.m, coarse_n=Gc.n, W=W, P=P,
-                        time_s=round(time.perf_counter() - t0, 6))
+                        time_s=round(dt, 6))
             break
         rec = dict(phase="dist-coarsen", level=level, n=G.n, m=G.m,
                    coarse_n=Gc.n, W=W, P=P, contraction=cfg.contraction,
-                   weights=cfg.weights,
-                   time_s=round(time.perf_counter() - t0, 6))
+                   weights=cfg.weights, time_s=round(dt, 6))
         if cstats is not None:
             rec.update(exchange_s=cstats["exchange_s"],
                        payload_bytes=cstats["payload_bytes"])
@@ -189,24 +206,26 @@ def dist_partition_impl(g: Graph,
     # ---- uncoarsening: project + distributed refine/balance ------------
     lvec = np.full(k, l_final, dtype=np.int64)
     for lvl, (Gf, mapping, fshards) in enumerate(reversed(hierarchy)):
-        t0 = time.perf_counter()
-        part = part[mapping]
         lvl_seed = uncoarsen_seed(cfg.seed, lvl, stream=1)
         bal_stats: Dict = {}
-        part = dist_refine_and_balance(
-            Gf, part, lvec, P, num_iterations=cfg.refine_iterations,
-            num_chunks=cfg.num_chunks,
-            seed=lvl_seed, use_grid=use_grid, mesh=mesh,
-            shards=fshards, weights=cfg.weights, balance=cfg.balance,
-            kernel=cfg.kernel, refine=cfg.refine,
-            balance_stats=bal_stats)
+        with spans.span("dist.uncoarsen_level", level=lvl, n=Gf.n, m=Gf.m,
+                        P=P):
+            t0 = time.perf_counter()
+            part = part[mapping]
+            part = dist_refine_and_balance(
+                Gf, part, lvec, P, num_iterations=cfg.refine_iterations,
+                num_chunks=cfg.num_chunks,
+                seed=lvl_seed, use_grid=use_grid, mesh=mesh,
+                shards=fshards, weights=cfg.weights, balance=cfg.balance,
+                kernel=cfg.kernel, refine=cfg.refine,
+                balance_stats=bal_stats)
+            dt = time.perf_counter() - t0
         if trace is not None:
             rec = dict(phase="dist-uncoarsen", level=lvl, n=Gf.n,
                        m=Gf.m, blocks=k, P=P, seed=lvl_seed,
                        balance=cfg.balance,
                        balance_rounds=bal_stats.get("rounds"),
-                       cut=metrics.edge_cut(Gf, part),
-                       time_s=round(time.perf_counter() - t0, 6))
+                       cut=trace_cut(Gf, part), time_s=round(dt, 6))
             if cfg.refine != "lp":
                 # unconstrained tier: the balancer doubles as the
                 # feasibility afterburner, so balance_rounds IS the
@@ -216,9 +235,6 @@ def dist_partition_impl(g: Graph,
                            penalty=penalty_schedule(cfg.refine_iterations),
                            repair_rounds=bal_stats.get("rounds"))
             trace_event(trace, **rec)
-    from ..kernels import dispatch
-    for rec in dispatch.drain_fallback_records():
-        trace_event(trace, **rec)
     return part
 
 

@@ -18,14 +18,15 @@ shape exceeds the kernel's VMEM budget (see ``tiled_bytes``); the fallback
 is safe because both paths are bit-identical by construction, and it is
 *observable*, not silent: every decision is recorded via
 ``report_fallback`` (a one-shot warning per kernel plus a
-``kernel-fallback`` trace record the drivers drain into the request
-trace through ``drain_fallback_records``).
+``kernel-fallback`` record in the trace of the request that made it,
+through ``repro.spans``).
 """
 from __future__ import annotations
 
 import functools
 import warnings
-from typing import Dict, List
+
+from .. import spans
 
 KERNEL_MODES = ("auto", "fused", "composed")
 
@@ -78,11 +79,10 @@ def tiled_bytes(*shape: int) -> int:
 # --- fallback observability -------------------------------------------
 # A fused wrapper that falls back to the composed path is *correct* but
 # silently loses the kernel speedup; callers used to find out only by
-# profiling. Decision sites call ``report_fallback`` so the drivers can
-# drain ``kernel-fallback`` records into the run trace, and the first
-# fallback per kernel raises a one-shot ``UserWarning``.
+# profiling. Decision sites call ``report_fallback``, which writes a
+# ``kernel-fallback`` record into the active request's trace, and the
+# first fallback per kernel raises a one-shot ``UserWarning``.
 
-_fallback_records: List[Dict] = []
 _fallback_warned: set = set()
 
 
@@ -90,7 +90,7 @@ def report_fallback(kernel: str, estimated_bytes: int,
                     budget: int = VMEM_BUDGET_BYTES,
                     detail: str = "") -> None:
     """Record one fused->composed fallback decision."""
-    _fallback_records.append({
+    spans.append({
         "event": "kernel-fallback",
         "kernel": kernel,
         "estimated_bytes": int(estimated_bytes),
@@ -109,14 +109,6 @@ def report_fallback(kernel: str, estimated_bytes: int,
             UserWarning, stacklevel=3)
 
 
-def drain_fallback_records() -> List[Dict]:
-    """Return-and-clear the pending ``kernel-fallback`` records."""
-    records = list(_fallback_records)
-    _fallback_records.clear()
-    return records
-
-
-def reset_fallback_state() -> None:
-    """Forget pending records and re-arm the one-shot warnings."""
-    _fallback_records.clear()
+def reset_fallback_warnings() -> None:
+    """Re-arm the one-shot warnings."""
     _fallback_warned.clear()

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from ... import spans
 from .seg_merge import I32_MAX, padded_lanes, seg_merge, seg_merge_vmem_bytes
 from ..dispatch import VMEM_BUDGET_BYTES
 
@@ -50,8 +51,9 @@ def dedup_arcs_fused(csrc: np.ndarray, cdst: np.ndarray, w: np.ndarray,
     dst32 = np.concatenate([cdst.astype(np.int32),
                             np.full(pad, I32_MAX, np.int32)])
     w32 = np.concatenate([w.astype(np.int32), np.zeros(pad, np.int32)])
-    s_src, s_dst, tot, first = (np.asarray(x) for x in seg_merge(
-        src32, dst32, w32, interpret=interpret))
+    s_src, s_dst, tot, first = (spans.fetch(x) for x in seg_merge(
+        spans.upload(src32), spans.upload(dst32), spans.upload(w32),
+        interpret=interpret))
     take = (s_src < int(I32_MAX)) & (first != 0)
     return (s_src[take].astype(np.int64), s_dst[take].astype(np.int64),
             tot[take].astype(np.int64))

@@ -70,7 +70,8 @@ def main() -> int:
                          "best=unconstrained); an explicit --refine wins "
                          "— docs/SERVING.md")
     ap.add_argument("--trace", action="store_true",
-                    help="also print the per-level trace records")
+                    help="also print the trace records (per-level "
+                         "records, kernel-fallback events and spans)")
     args = ap.parse_args()
 
     # compile cache and device forcing first — repro.api.runtime errors

@@ -346,7 +346,7 @@ def main() -> int:
                                                 trace=tr)
                 s_b = metrics.summarize(g, part_b, args.k, 0.03)
                 seeds = [t["seed"] for t in tr
-                         if t["phase"] == "dist-uncoarsen"]
+                         if t.get("phase") == "dist-uncoarsen"]
                 levels = len(seeds)
                 report(f"balance.no_host_gather_{wmode}",
                        s_b["feasible"] and calls["n"] == 0 and
@@ -385,6 +385,7 @@ def main() -> int:
     if args.test in ("all", "api"):
         from repro.api import (PartitionRequest, Partitioner,
                                PartitionSession)
+        from repro.core.deep_mgp import level_records
         engine = Partitioner()
 
         # facade(dist-grid) must reproduce the direct driver bit-exactly
@@ -394,7 +395,7 @@ def main() -> int:
         want = dist_partition_impl(g, args.k, P, cfg=cfg, use_grid=True)
         report("api.dist_matches_driver",
                res.feasible and np.array_equal(res.assignment, want),
-               cut=res.cut, levels=len(res.trace))
+               cut=res.cut, levels=len(level_records(res.trace)))
 
         # feasibility flag must agree with the metrics module
         report("api.feasible_flag",

@@ -181,6 +181,7 @@ def stacked_level0_labels(
     (num_chunks, iterations) signature fall into separate stacks."""
     import jax.numpy as jnp
 
+    from .. import spans
     from ..core import lp
     from ..core.coarsening import cluster_finish, cluster_prepare
     from ..core.coarsening import cluster_seed
@@ -233,11 +234,11 @@ def stacked_level0_labels(
             jnp.arange(n_pad + 1, dtype=jnp.int32),
             (R, n_pad + 1),
         )
-        vw = jnp.asarray(np.stack(vw_rows))
+        vw = spans.upload(np.stack(vw_rows))
         cluster_w = vw
-        src = jnp.asarray(np.stack(src_rows))
-        dst = jnp.asarray(np.stack(dst_rows))
-        w = jnp.asarray(np.stack(w_rows))
+        src = spans.upload(np.stack(src_rows))
+        dst = spans.upload(np.stack(dst_rows))
+        w = spans.upload(np.stack(w_rows))
         W = jnp.asarray(np.asarray(w_bound, dtype=np.int32))
         for it in range(num_iterations):
             salts = [cluster_seed(s, it) for s in seeds]
@@ -245,7 +246,7 @@ def stacked_level0_labels(
             labels, cluster_w = lp.cluster_iteration_stacked(
                 labels, cluster_w, src, dst, w, vw, W, it_seeds, n=n_pad
             )
-        labels_np = np.asarray(labels)
+        labels_np = spans.fetch(labels)
         for row, i in enumerate(idxs):
             _, plan, perm, g2, _ = prepped[i]
             out[i] = cluster_finish(

@@ -133,7 +133,12 @@ def _dedup_inputs():
     return csrc, cdst, w
 
 
+def _fallback_events(trace):
+    return [t for t in trace if t.get("event") == "kernel-fallback"]
+
+
 def test_fallback_boundary_exact_budget_stays_fused(monkeypatch):
+    from repro import spans
     from repro.core import contraction
     from repro.kernels import dispatch
     from repro.kernels.seg_merge import ops as seg_ops
@@ -143,16 +148,20 @@ def test_fallback_boundary_exact_budget_stays_fused(monkeypatch):
     est = seg_merge_vmem_bytes(csrc.size)
     # ops modules freeze the budget at import: patch the frozen copy
     monkeypatch.setattr(seg_ops, "VMEM_BUDGET_BYTES", est)
-    dispatch.reset_fallback_state()
-    with warnings.catch_warnings():
+    dispatch.reset_fallback_warnings()
+    trace = []
+    with warnings.catch_warnings(), spans.recording(trace):
         warnings.simplefilter("error")  # any fallback warning -> fail
         out = contraction.dedup_arcs(csrc, cdst, w, kernel="fused")
-    assert dispatch.drain_fallback_records() == []
+    assert _fallback_events(trace) == []
+    assert [t["span"] for t in trace] == ["level.h2d"] * 3 + \
+        ["wait"] * 4 + ["level.dedup"]
     want = contraction.dedup_arcs(csrc, cdst, w, kernel="composed")
     assert all(np.array_equal(a, b) for a, b in zip(out, want))
 
 
 def test_fallback_one_past_budget_warns_once_and_records(monkeypatch):
+    from repro import spans
     from repro.core import contraction
     from repro.kernels import dispatch
     from repro.kernels.seg_merge import ops as seg_ops
@@ -161,18 +170,25 @@ def test_fallback_one_past_budget_warns_once_and_records(monkeypatch):
     csrc, cdst, w = _dedup_inputs()
     est = seg_merge_vmem_bytes(csrc.size)
     monkeypatch.setattr(seg_ops, "VMEM_BUDGET_BYTES", est - 1)
-    dispatch.reset_fallback_state()
-    with pytest.warns(UserWarning, match="seg_merge"):
-        out = contraction.dedup_arcs(csrc, cdst, w, kernel="fused")
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")  # one-shot: second time silent
-        contraction.dedup_arcs(csrc, cdst, w, kernel="fused")
-    records = dispatch.drain_fallback_records()
+    dispatch.reset_fallback_warnings()
+    trace = []
+    with spans.recording(trace):
+        with pytest.warns(UserWarning, match="seg_merge"):
+            out = contraction.dedup_arcs(csrc, cdst, w, kernel="fused")
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")  # one-shot: second time silent
+            contraction.dedup_arcs(csrc, cdst, w, kernel="fused")
+    records = _fallback_events(trace)
     assert len(records) == 2  # every decision recorded, warned once
-    assert records[0]["event"] == "kernel-fallback"
-    assert records[0]["kernel"] == "seg_merge"
-    assert records[0]["estimated_bytes"] == est
-    assert dispatch.drain_fallback_records() == []  # drained
+    assert records[0] == {"event": "kernel-fallback", "kernel": "seg_merge",
+                          "estimated_bytes": est,
+                          "budget_bytes": dispatch.VMEM_BUDGET_BYTES,
+                          "detail": "dedup_arcs (int32/VMEM envelope)"}
+    # outside a recorder a decision is only warned about: no global
+    # list keeps it for whichever request drains next
+    contraction.dedup_arcs(csrc, cdst, w, kernel="fused")
+    assert len(_fallback_events(trace)) == 2
+    assert not hasattr(dispatch, "drain_fallback_records")
     want = contraction.dedup_arcs(csrc, cdst, w, kernel="composed")
     assert all(np.array_equal(a, b) for a, b in zip(out, want))
 
@@ -186,25 +202,29 @@ def test_fallback_records_drain_into_partition_trace(monkeypatch):
     from repro.kernels.seg_merge import ops as seg_ops
 
     # force every fused path over budget: the whole run falls back to
-    # the composed kernels and the driver drains the records into the
-    # trace (also keeps this test fast — no interpret-mode Pallas)
+    # the composed kernels and each decision lands in the trace of the
+    # partition that made it (also keeps this test fast — no
+    # interpret-mode Pallas)
     monkeypatch.setattr(move_ops, "VMEM_BUDGET_BYTES", 0)
     monkeypatch.setattr(bal_ops, "VMEM_BUDGET_BYTES", 0)
     monkeypatch.setattr(seg_ops, "VMEM_BUDGET_BYTES", 0)
-    dispatch.reset_fallback_state()
+    dispatch.reset_fallback_warnings()
     g = generators.make("rgg2d", 300, 6.0, seed=2)
     cfg = deep_mgp.PartitionerConfig(contraction_limit=64,
                                      ip_repetitions=1, num_chunks=2,
                                      kernel="fused")
-    trace = []
+    traces = [[], []]
     with warnings.catch_warnings():
         warnings.simplefilter("ignore", UserWarning)
-        deep_mgp.partition(g, 2, cfg, trace=trace)
-    events = [t for t in trace if t.get("event") == "kernel-fallback"]
-    assert events, trace
-    assert all(t["budget_bytes"] == dispatch.VMEM_BUDGET_BYTES or
-               t["budget_bytes"] >= 0 for t in events)
-    assert dispatch.drain_fallback_records() == []
+        for trace in traces:
+            deep_mgp.partition(g, 2, cfg, trace=trace)
+    events = [_fallback_events(t) for t in traces]
+    assert events[0], traces[0]
+    assert {"lp_move", "seg_merge"} <= {t["kernel"] for t in events[0]}
+    # the second partition gets its own decisions, none left over
+    assert events[1] == events[0]
+    builds = [t for t in traces[0] if t.get("span") == "level.ell_build"]
+    assert builds and not any(t["attrs"]["used"] for t in builds)
 
 
 # ---------------------------------------------------------------------------

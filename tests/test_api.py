@@ -175,7 +175,7 @@ def test_dist_p1_sharded_owner_memory_model(g):
                          devices=1, contraction="sharded",
                          weights="owner"))
     assert res.feasible
-    coarsen = [t for t in res.trace if t["phase"] == "dist-coarsen"]
+    coarsen = [t for t in res.trace if t.get("phase") == "dist-coarsen"]
     assert coarsen and all(t["contraction"] == "sharded"
                            and "exchange_s" in t for t in coarsen)
 
@@ -197,11 +197,13 @@ def test_result_summary_and_trace(g, single_result):
     json.dumps(s)                       # JSON-serializable
     assert s["backend"] == "single" and s["n"] == g.n and s["m"] == g.m
     assert res.trace, "per-level trace must be populated"
-    phases = [t["phase"] for t in res.trace]
+    records = [t for t in res.trace if "phase" in t]
+    phases = [t["phase"] for t in records]
     assert phases[0] == "coarsen" and phases[-1] == "final"
-    assert all("time_s" in t for t in res.trace)
+    assert all("time_s" in t for t in records)
+    assert s["levels"] == len(records)
     # the final trace record's cut is the result's cut
-    assert res.trace[-1]["cut"] == res.cut == metrics.edge_cut(
+    assert records[-1]["cut"] == res.cut == metrics.edge_cut(
         g, res.assignment)
 
 
