@@ -126,18 +126,30 @@ def from_coo(n: int,
 
 
 def permute(g: Graph, perm: np.ndarray) -> Tuple[Graph, np.ndarray]:
-    """Relabel vertices: new id of old vertex v is perm[v]. Returns (graph, inv)."""
+    """Relabel vertices: new id of old vertex v is perm[v]. Returns (graph, inv).
+
+    Each row keeps its arcs, so new row ``i`` is old row ``inv[i]`` gathered
+    in one index pass; a stable sort of the one key ``row * n + head``
+    (already grouped by row; int64 for any n below 3e9) then orders each row
+    by its new heads, parallel arcs staying in CSR order.
+    """
+    n = g.n
     inv = np.empty_like(perm)
-    inv[perm] = np.arange(g.n, dtype=perm.dtype)
-    src = g.arc_tails()
-    new_src = perm[src]
-    new_dst = perm[g.adjncy]
-    order = np.lexsort((new_dst, new_src))
-    indptr = np.zeros(g.n + 1, dtype=np.int64)
-    np.add.at(indptr, new_src + 1, 1)
-    g2 = Graph(indptr=np.cumsum(indptr),
-               adjncy=new_dst[order].astype(g.adjncy.dtype),
-               eweights=g.eweights[order],
+    inv[perm] = np.arange(n, dtype=perm.dtype)
+    deg = np.diff(g.indptr)[inv]
+    indptr = np.zeros(n + 1, dtype=np.int64)
+    np.cumsum(deg, out=indptr[1:])
+    take = np.repeat(g.indptr[inv] - indptr[:-1], deg) + np.arange(g.m)
+    new_dst = perm[g.adjncy[take]]
+    key = np.repeat(np.arange(n, dtype=np.int64) * n, deg)
+    key += new_dst
+    order = np.argsort(key, kind="stable")
+    del key  # dropping each m-long array once used keeps the peak low
+    adjncy = new_dst[order].astype(g.adjncy.dtype)
+    del new_dst
+    take = take[order]
+    del order
+    g2 = Graph(indptr=indptr, adjncy=adjncy, eweights=g.eweights[take],
                vweights=g.vweights[inv])
     return g2, inv
 
