@@ -209,18 +209,21 @@ def rebalance(g: Graph,
         fused_ell = None
         if dispatch.resolve_kernel_mode(kernel) == "fused":
             from ..kernels.bal_round import ops as bal_ops
-            with spans.span("level.ell_build", kernel="bal_round") as eb:
-                idx, ew = bal_ops.build_balance_ell(g, n_pad)
-                used = bal_ops.balance_ell_fits(idx.shape[0], idx.shape[1],
-                                                restricted=restricted)
-                eb.set(used=used, rows=idx.shape[0], lanes=idx.shape[1])
+            # the gate reads the ELL's shape: a refused ELL is not built
+            rows, lanes = bal_ops.balance_ell_shape(g, n_pad)
+            used = bal_ops.balance_ell_fits(rows, lanes,
+                                            restricted=restricted)
+            with spans.span("level.ell_build", kernel="bal_round",
+                            used=used, rows=rows, lanes=lanes):
+                if used:
+                    idx, ew = bal_ops.build_balance_ell(g, n_pad)
             if used:
                 fused_ell = (spans.upload(idx), spans.upload(ew))
             else:
                 dispatch.report_fallback(
                     "bal_round",
                     bal_ops.bal_scores_vmem_bytes(
-                        idx.shape[0], idx.shape[1], bal_ops.ROW_TILE,
+                        rows, lanes, bal_ops.ROW_TILE,
                         restricted=restricted),
                     detail="rebalance")
         if fused_ell is None:

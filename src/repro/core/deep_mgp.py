@@ -20,7 +20,7 @@ from .coarsening import cluster
 from .contraction import contract
 from .initial_partition import (bipartition, distribute_counts,
                                 partition_into_counts, split_count)
-from .refinement import balance_and_refine
+from .refinement import balance_and_refine, block_bucket
 
 log = logging.getLogger("repro.deep_mgp")
 
@@ -229,6 +229,8 @@ def extend_partition(g: Graph, part: np.ndarray, block_k: np.ndarray,
                 half = bipartition(graphs[b], k1, k2, l_final, rng,
                                    cfg.ip_repetitions)
                 sp.add("bipartitions", 1)
+                sp.add("vertices", graphs[b].n)
+                sp.add("arcs", graphs[b].m)
                 new_part[ids[b]] = off + half
                 new_counts.extend([k1, k2])
                 parent.extend([b, b])
@@ -237,7 +239,8 @@ def extend_partition(g: Graph, part: np.ndarray, block_k: np.ndarray,
         part = new_part
         # sibling-restricted refinement pass (cheap cleanup of the split)
         lv = _l_vec(block_k, l_final)
-        with spans.span("extend.refine", n=g.n, m=g.m, blocks=off):
+        with spans.span("extend.refine", n=g.n, m=g.m, blocks=off,
+                        k_pad=block_bucket(off)):
             part = balance_and_refine(
                 g, part, lv, parent=np.asarray(parent, dtype=np.int64),
                 num_iterations=1, num_chunks=cfg.num_chunks,

@@ -25,26 +25,31 @@ def _neighbors(g: Graph, v: int) -> Tuple[np.ndarray, np.ndarray]:
 def ggg_bipartition(g: Graph, target1: int, lmax0: int, lmax1: int,
                     rng: np.random.Generator) -> np.ndarray:
     """Greedy graph growing: grow block 1 from a random seed by max gain
-    until it reaches ``target1`` (and block 0 fits ``lmax0``)."""
+    until it reaches ``target1`` (and block 0 fits ``lmax0``).
+
+    The loop touches one vertex and its neighbours per step, so its
+    state lives in Python lists: numpy's per-element indexing costs more
+    than the step's work."""
     n = g.n
-    part = np.zeros(n, dtype=np.int64)
     if n == 0:
-        return part
+        return np.zeros(n, dtype=np.int64)
     vw = g.vweights
     total = int(vw.sum())
     min_w1 = max(0, total - lmax0)
     # initial gains: joining an empty B1 loses all incident weight
     wdeg = np.zeros(n, dtype=np.int64)
     np.add.at(wdeg, g.arc_tails(), g.eweights)
-    gain = -wdeg
-    in1 = np.zeros(n, dtype=bool)
+    gain = (-wdeg).tolist()
+    weight = vw.tolist()
+    indptr, adj, ew = (g.indptr.tolist(), g.adjncy.tolist(),
+                       g.eweights.tolist())
+    in1 = [False] * n
     heap: list = []
+    push, pop = heapq.heappush, heapq.heappop
     seed = int(rng.integers(n))
-    heapq.heappush(heap, (0, seed))
+    push(heap, (0, seed))
     gain[seed] = 0
     w1 = 0
-    visited_push = np.zeros(n, dtype=bool)
-    visited_push[seed] = True
     # iteration guard: when no remaining vertex fits lmax1 but min_w1 is
     # unreachable (overweight parent block), the grow loop cannot make
     # progress — bail out and let the balancer repair feasibility
@@ -54,32 +59,32 @@ def ggg_bipartition(g: Graph, target1: int, lmax0: int, lmax1: int,
         if budget <= 0:
             break
         if not heap:
-            rest = np.flatnonzero(~in1)
+            rest = np.flatnonzero(~np.array(in1, dtype=bool))
             if rest.size == 0:
                 break
             fits = rest[vw[rest] + w1 <= lmax1]
             if fits.size == 0:
                 break
             v = int(rng.choice(fits))
-            heapq.heappush(heap, (-int(gain[v]), v))
-            visited_push[v] = True
+            push(heap, (-gain[v], v))
             continue
-        negg, v = heapq.heappop(heap)
+        negg, v = pop(heap)
         if in1[v] or -negg != gain[v]:
             continue  # stale entry
-        if w1 + int(vw[v]) > lmax1:
+        if w1 + weight[v] > lmax1:
             continue
         in1[v] = True
-        w1 += int(vw[v])
-        nbr, nw = _neighbors(g, v)
-        upd = nbr[~in1[nbr]]
-        uw = nw[~in1[nbr]]
-        gain[upd] += 2 * uw
-        for u, _ in zip(upd.tolist(), uw.tolist()):
-            heapq.heappush(heap, (-int(gain[u]), u))
-            visited_push[u] = True
-    part[in1] = 1
-    return part
+        w1 += weight[v]
+        a0, a1 = indptr[v], indptr[v + 1]
+        upd = [(u, w) for u, w in zip(adj[a0:a1], ew[a0:a1]) if not in1[u]]
+        # each gain from its value before this step, the last arc to a
+        # repeated neighbour winning, as a numpy ``gain[upd] += 2 * uw``
+        new = [gain[u] + 2 * w for u, w in upd]
+        for (u, _), x in zip(upd, new):
+            gain[u] = x
+        for u, _ in upd:
+            push(heap, (-gain[u], u))
+    return np.array(in1, dtype=np.int64)
 
 
 def fm_lite_refine(g: Graph, part: np.ndarray, lmax: np.ndarray,

@@ -23,6 +23,12 @@ from . import lp
 _BIG_L = np.int32(2**31 - 1)
 
 
+def block_bucket(k: int, min_bucket: int = 64) -> int:
+    """The padded block count (``k_pad``) of ``k`` blocks: the power of
+    two at or above ``k``, and at least ``min_bucket``."""
+    return max(min_bucket, 1 << max(0, (int(k) - 1)).bit_length())
+
+
 def pad_blocks(block_w: np.ndarray, l_max_vec: np.ndarray,
                parent: Optional[np.ndarray], min_bucket: int = 64
                ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, int]:
@@ -46,7 +52,7 @@ def pad_blocks(block_w: np.ndarray, l_max_vec: np.ndarray,
             "pad_blocks: block weights must fit int32 (max "
             f"{int(block_w.max())}); totals >= 2^31 are not supported by "
             "the int32 jit path")
-    k_pad = max(min_bucket, 1 << max(0, (k - 1)).bit_length())
+    k_pad = block_bucket(k, min_bucket)
     if k_pad == k:
         p = parent if parent is not None else np.arange(k)
         return (block_w.astype(np.int32),

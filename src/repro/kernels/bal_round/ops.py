@@ -24,12 +24,17 @@ from ..dispatch import VMEM_BUDGET_BYTES
 from ..lp_move.ops import LANE, ROW_TILE, _round_up, ell_from_csr
 
 
+def balance_ell_shape(g, n_pad: int):
+    """(R, D) of ``build_balance_ell(g, n_pad)``, without building it."""
+    deg = np.diff(g.indptr)
+    return (_round_up(n_pad + 1, ROW_TILE),
+            _round_up(int(deg.max()) if deg.size else 1, LANE))
+
+
 def build_balance_ell(g, n_pad: int):
     """(R, D) neighbor-id / weight ELL over the (n_pad + 1) label-table
     row space (tile-padded); -1 / 0 padding."""
-    deg = np.diff(g.indptr)
-    D = _round_up(int(deg.max()) if deg.size else 1, LANE)
-    R = _round_up(n_pad + 1, ROW_TILE)
+    R, D = balance_ell_shape(g, n_pad)
     idx = np.full((R, D), -1, dtype=np.int32)
     w = np.zeros((R, D), dtype=np.int32)
     idx_full, w_full = ell_from_csr(np.asarray(g.indptr),

@@ -93,6 +93,55 @@ def test_rebalance_feasible_early_return(monkeypatch):
     assert stats["rounds"] == 0 and stats["gather_bytes"] == 0
 
 
+@pytest.mark.parametrize("parent", [None, "siblings"])
+def test_rebalance_builds_no_refused_ell(monkeypatch, parent):
+    """The fused balancer's gate reads the ELL's shape: with every ELL
+    over its budget none is built, the fallback is recorded, and the
+    answer is the composed balancer's."""
+    import warnings
+
+    from repro import spans
+    from repro.kernels import dispatch
+    from repro.kernels.bal_round import ops as bal_ops
+
+    g = generators.make("rgg2d", 600, 8.0, seed=7)
+    k = 8
+    lmax = np.full(k, metrics.l_max(g.total_vweight, k, 0.03,
+                                    int(g.vweights.max())), dtype=np.int64)
+    part = np.zeros(g.n, dtype=np.int64)
+    par = None if parent is None else np.arange(k) // 2
+    if par is not None:
+        part[g.n // 2:] = 2
+    want = rebalance(g, part, lmax, parent=par, seed=3, kernel="composed")
+
+    def refused(*a, **kw):
+        raise AssertionError("a refused ELL was built")
+
+    monkeypatch.setattr(bal_ops, "VMEM_BUDGET_BYTES", 0)
+    monkeypatch.setattr(bal_ops, "build_balance_ell", refused)
+    dispatch.reset_fallback_warnings()
+    trace = []
+    with warnings.catch_warnings(), spans.recording(trace):
+        warnings.simplefilter("ignore", UserWarning)
+        got = rebalance(g, part, lmax, parent=par, seed=3, kernel="fused")
+    assert np.array_equal(got, want)
+    (build,) = [r for r in trace if r.get("span") == "level.ell_build"]
+    n_pad = next(r for r in trace
+                 if r.get("span") == "level.balance")["attrs"]["n_pad"]
+    assert build["attrs"]["used"] is False
+    assert (build["attrs"]["rows"], build["attrs"]["lanes"]) == \
+        bal_ops.balance_ell_shape(g, n_pad)
+    assert [r["kernel"] for r in trace if r.get("event")] == ["bal_round"]
+
+
+def test_balance_ell_shape_is_the_built_shape():
+    from repro.kernels.bal_round import ops as bal_ops
+    g = generators.make("rgg2d", 700, 9.0, seed=8)
+    for n_pad in (700, 1023, 1024, 4096):
+        idx, w = bal_ops.build_balance_ell(g, n_pad)
+        assert idx.shape == w.shape == bal_ops.balance_ell_shape(g, n_pad)
+
+
 # ---------------------------------------------------------------------------
 # int32 boundary: exact at 2^31 - 1, clear error at 2^31
 # ---------------------------------------------------------------------------
